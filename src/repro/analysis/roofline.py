@@ -234,9 +234,6 @@ class Roofline:
 
 def analyze(compiled) -> Roofline:
     cost = compiled.cost_analysis()
-    # cost_analysis returns a dict (or a 1-elem list of dicts on some paths)
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0]
     coll = collective_bytes(compiled.as_text())
     return Roofline(
         flops_per_chip=float(cost.get("flops", 0.0)),

@@ -15,6 +15,7 @@ ZeRO-1: optimizer state additionally shards its largest replicated axis over
 from __future__ import annotations
 
 import logging
+from collections import Counter
 from typing import Mapping
 
 import jax
@@ -101,18 +102,6 @@ def param_pspecs(boxed_tree, mesh: Mesh | None = None,
         return P(*entries)
 
     return jax.tree_util.tree_map(fit, boxed_tree, is_leaf=is_boxed)
-
-
-def get_shard_map():
-    """The manual-SPMD entry point across jax versions: ``jax.shard_map``
-    (>= 0.6) or ``jax.experimental.shard_map.shard_map`` — the one shim
-    both the sharded serving engine and the packed superstep use."""
-    try:
-        from jax import shard_map  # type: ignore[attr-defined]
-        return shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-        return shard_map
 
 
 def slots_mesh(num_shards: int, devices=None) -> Mesh:
@@ -284,6 +273,12 @@ def measure_collective_seconds(mesh: Mesh, payload_bytes, axis: str = "model",
     schedule outside and the engine attributes ``probe x rounds`` per
     dispatch.
 
+    Payloads of one size run back to back on ONE buffer (a loop whose
+    every step depends on the last, as the layers of the verify do), so the
+    probe holds one buffer per distinct size, not the whole schedule: a
+    4-slot tensor-parallel round of ``paper-ldm-dit`` is 56 psums of 85 MB,
+    and buffers for all of them would take 9.5 GB of each chip.
+
     ``kind`` selects the probed collective: ``"psum"`` (tensor-parallel
     all-reduces, and the EP/SP output combines) or ``"all_to_all"`` (the
     EP token exchange and the Ulysses sequence<->head trades).  The two are
@@ -294,27 +289,31 @@ def measure_collective_seconds(mesh: Mesh, payload_bytes, axis: str = "model",
 
     if kind not in ("psum", "all_to_all"):
         raise ValueError(f"unknown collective kind {kind!r}")
-    payloads = [max(int(b) // 4, 1) for b in payload_bytes]
-    if not payloads or axis not in mesh.axis_names or mesh.shape[axis] <= 1:
+    # elements per payload -> how many collectives of that size
+    counts = Counter(max(int(b) // 4, 1) for b in payload_bytes)
+    if not counts or axis not in mesh.axis_names or mesh.shape[axis] <= 1:
         return 0.0
     world = int(mesh.shape[axis])
-    smap = get_shard_map()
 
     if kind == "psum":
-        def body(*xs):
-            return tuple(jax.lax.psum(x, axis) for x in xs)
-        shapes = [(n,) for n in payloads]
+        def op(x):
+            return jax.lax.psum(x, axis)
+        shapes = [(n,) for n in counts]
     else:
         # a (world, n/world) buffer keeps its shape under the tiled
         # all_to_all while every element still crosses the axis
-        def body(*xs):
-            return tuple(
-                jax.lax.all_to_all(x, axis, 0, 0, tiled=True) for x in xs)
-        shapes = [(world, max(n // world, 1)) for n in payloads]
+        def op(x):
+            return jax.lax.all_to_all(x, axis, 0, 0, tiled=True)
+        shapes = [(world, max(n // world, 1)) for n in counts]
+
+    def body(*xs):
+        return tuple(jax.lax.fori_loop(0, c, lambda _, x: op(x), x)
+                     for x, c in zip(xs, counts.values()))
 
     rep = P()
-    fn = jax.jit(smap(body, mesh=mesh, in_specs=(rep,) * len(payloads),
-                      out_specs=(rep,) * len(payloads), check_rep=False))
+    fn = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(rep,) * len(shapes),
+        out_specs=(rep,) * len(shapes), check_vma=False))
     xs = tuple(jax.device_put(np.zeros(s, np.float32),
                               NamedSharding(mesh, P())) for s in shapes)
     jax.block_until_ready(fn(*xs))  # compile + warm

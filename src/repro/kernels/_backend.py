@@ -1,20 +1,15 @@
 """Shared Pallas backend resolution for every kernel wrapper.
 
 One question, answered once: should a ``pallas_call`` run compiled or in
-interpret mode on THIS process's default backend?  Before this helper each
-ops.py decided ``interpret = not on_tpu()``, which silently sent GPU runs
-down the interpret path (a pure-Python emulation, orders of magnitude slower
-than either the Triton lowering or plain XLA) with no error and no log line.
+interpret mode on THIS process's default backend?
 
 Resolution order:
 
   1. ``REPRO_PALLAS_INTERPRET=0/1`` env override — forced compiled / forced
      interpret, whatever the backend (the escape hatch for debugging a
-     kernel on TPU or smoke-testing the compiled path in CI).
+     kernel on TPU).
   2. TPU: compiled (the Mosaic lowering is the native target).
-  3. GPU: compiled when the Pallas Triton lowering is importable in this
-     jaxlib, else interpret.
-  4. CPU (and anything else): interpret — Pallas has no CPU lowering.
+  3. CPU (and anything else): interpret — Pallas has no CPU lowering.
 
 The chosen path is logged ONCE per process per backend, so a serving log
 always shows which lane the kernels took.
@@ -33,17 +28,6 @@ _log = logging.getLogger("repro.kernels")
 _announced: set = set()
 
 
-def _gpu_triton_available() -> bool:
-    """Pallas GPU support ships as the Triton lowering; probe for it rather
-    than assuming every jaxlib GPU build carries it."""
-    try:
-        import jax._src.pallas.triton  # noqa: F401
-
-        return True
-    except ImportError:
-        return False
-
-
 def resolve_interpret(interpret: bool | None = None) -> bool:
     """The interpret flag a kernel wrapper should pass to ``pallas_call``.
 
@@ -60,11 +44,6 @@ def resolve_interpret(interpret: bool | None = None) -> bool:
         reason = f"REPRO_PALLAS_INTERPRET={env}"
     elif backend == "tpu":
         chosen, reason = False, "TPU Mosaic lowering"
-    elif backend == "gpu":
-        if _gpu_triton_available():
-            chosen, reason = False, "GPU Triton lowering"
-        else:
-            chosen, reason = True, "GPU without Pallas Triton support"
     else:
         chosen, reason = True, f"{backend} has no Pallas lowering"
     if backend not in _announced:

@@ -1,20 +1,22 @@
-"""Pallas TPU kernel: ragged row gather/scatter for packed verification.
+"""Pallas TPU kernels: ragged row gather/scatter for packed verification.
 
 The pack op is pure data movement — each output row is one dynamically
-indexed row copy — so the kernel's job is to keep the copies inside VMEM and
-off the HLO gather/scatter path (which XLA lowers to one dynamic-slice per
-row plus a concatenate on TPU).
+indexed row copy — so the kernel's job is to move exactly the packed rows
+and keep the copies off the HLO gather/scatter path (which XLA lowers to one
+dynamic-slice per row plus a concatenate on TPU).
 
-Layout: the source/destination row table lives wholly in VMEM (it is the
-slot-batch's speculation window, ``num_slots * theta`` rows of a lane-padded
-feature axis — small by construction); the packed side is blocked by ROW_BLK
-rows.  The row index map rides in SMEM as scalar-prefetch-style operands.
+Layout: every table stays in HBM.  The row index map is scalar-prefetched
+into SMEM, and each BlockSpec's index map reads it, so the Pallas pipeline
+DMAs one packed row per grid step (double-buffered) and no table is ever
+held whole in VMEM — at a 1024x16 latent event a row is 64 KiB, so whole
+tables ran out of VMEM from 512 rows (the fused gather's four tables) or
+1024 rows (one table).  A row of D features
+(D % 128 == 0) is viewed as a lane-dense (D // 128, 128) tile.
 
-  gather grid step i: for each of its ROW_BLK packed rows p, one dynamic
-    row load  out[p, :] = src[idx[p], :].
-  scatter grid step i: zero the output on the first step (TPU grid steps are
-    sequential), then for each input row p a predicated dynamic row store
-    out[idx[p], :] = vals[p, :]; rows with idx[p] >= num_rows are dropped.
+  gather grid step p:  out[p] = src[idx[p]]
+  scatter grid step p: out[idx[p]] = vals[p], written into a zero table that
+    is aliased as the output (rows no step visits stay zero); rows with
+    idx[p] >= num_rows land on one extra drop row that is sliced off.
 """
 
 from __future__ import annotations
@@ -24,71 +26,88 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-ROW_BLK = 8
+from repro.kernels._padding import LANE
 
 
-def _gather_kernel(idx_ref, src_ref, out_ref):
-    for r in range(ROW_BLK):
-        out_ref[r, :] = src_ref[idx_ref[r, 0], :]
+def as_row_tiles(a: jax.Array) -> jax.Array:
+    """(N, D) -> (N, D // 128, 128): one lane-dense tile per row."""
+    return a.reshape(a.shape[0], a.shape[1] // LANE, LANE)
+
+
+def row_spec(tiles: jax.Array, row) -> pl.BlockSpec:
+    """One row tile of a (N, T, 128) table per grid step; ``row(p, idx)``
+    picks the row from the step and the prefetched index map."""
+    return pl.BlockSpec((None,) + tiles.shape[1:],
+                        lambda p, idx: (row(p, idx), 0, 0))
+
+
+def _copy_kernel(idx_ref, *refs):
+    n = len(refs) // 2
+    for src, dst in zip(refs[:n], refs[n:]):
+        dst[...] = src[...]
+
+
+def gather_tables(tables, idx, interpret: bool = False):
+    """out_k[p] = tables[k][idx[p]] for every (N, D_k) table in ONE kernel.
+
+    D_k % 128 == 0; idx: (M,) int32 in [0, N).  Returns [(M, D_k), ...]."""
+    tiles = [as_row_tiles(t) for t in tables]
+    (M,) = idx.shape
+    outs = pl.pallas_call(
+        _copy_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(M,),
+            in_specs=[row_spec(t, lambda p, idx: idx[p]) for t in tiles],
+            out_specs=[row_spec(t, lambda p, idx: p) for t in tiles],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((M,) + t.shape[1:], t.dtype)
+                   for t in tiles],
+        interpret=interpret,
+    )(idx, *tiles)
+    return [o.reshape(M, -1) for o in outs]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_rows_pallas(src, idx, interpret: bool = False):
-    """src: (N, D); idx: (M,) int32 in [0, N), M % ROW_BLK == 0, D % 128 == 0.
+    """src: (N, D); idx: (M,) int32 in [0, N), D % 128 == 0.
 
     Returns out: (M, D) with out[p] = src[idx[p]].
     """
-    N, D = src.shape
-    (M,) = idx.shape
-    assert M % ROW_BLK == 0, (M, ROW_BLK)
-    grid = (M // ROW_BLK,)
-    return pl.pallas_call(
-        _gather_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((ROW_BLK, 1), lambda i: (i, 0)),  # idx block
-            pl.BlockSpec((N, D), lambda i: (0, 0)),  # whole table in VMEM
-        ],
-        out_specs=pl.BlockSpec((ROW_BLK, D), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((M, D), src.dtype),
-        interpret=interpret,
-    )(idx[:, None], src)
+    (out,) = gather_tables([src], idx, interpret=interpret)
+    return out
 
 
-def _scatter_kernel(idx_ref, vals_ref, out_ref, *, num_rows: int):
-    @pl.when(pl.program_id(0) == 0)
-    def _():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    for r in range(ROW_BLK):
-        row = idx_ref[r, 0]
-
-        @pl.when(row < num_rows)
-        def _():
-            out_ref[row, :] = vals_ref[r, :]
+def _scatter_kernel(idx_ref, vals_ref, zeros_ref, out_ref):
+    del idx_ref, zeros_ref  # zeros_ref IS out_ref's buffer (aliased)
+    out_ref[...] = vals_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("num_rows", "interpret"))
 def scatter_rows_pallas(vals, idx, num_rows: int, interpret: bool = False):
-    """vals: (M, D); idx: (M,) int32; M % ROW_BLK == 0, D % 128 == 0.
+    """vals: (M, D); idx: (M,) int32; D % 128 == 0.
 
     Returns out: (num_rows, D) with out[idx[p]] = vals[p] for idx[p] in
     range; out-of-range rows dropped, unwritten rows zero.  In-range indices
     must be unique (the pack maps guarantee it).
     """
+    tiles = as_row_tiles(vals)
     (M,) = idx.shape
-    D = vals.shape[1]
-    assert M % ROW_BLK == 0, (M, ROW_BLK)
-    grid = (M // ROW_BLK,)
-    return pl.pallas_call(
-        functools.partial(_scatter_kernel, num_rows=num_rows),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((ROW_BLK, 1), lambda i: (i, 0)),
-            pl.BlockSpec((ROW_BLK, D), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((num_rows, D), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((num_rows, D), vals.dtype),
+    zeros = jnp.zeros((num_rows + 1,) + tiles.shape[1:], tiles.dtype)
+    out = pl.pallas_call(
+        _scatter_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(M,),
+            in_specs=[row_spec(tiles, lambda p, idx: p),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=row_spec(
+                zeros, lambda p, idx: jnp.minimum(idx[p], num_rows)),
+        ),
+        out_shape=jax.ShapeDtypeStruct(zeros.shape, zeros.dtype),
+        input_output_aliases={2: 0},  # operands: (idx, vals, zeros)
         interpret=interpret,
-    )(idx[:, None], vals)
+    )(idx, tiles, zeros)
+    return out[:num_rows].reshape(num_rows, -1)

@@ -12,16 +12,12 @@ import jax.numpy as jnp
 
 from repro.kernels._backend import resolve_interpret
 from repro.kernels._padding import LANE, pad_dim
-from repro.kernels.pack.kernel import (
-    ROW_BLK,
-    gather_rows_pallas,
-    scatter_rows_pallas,
-)
+from repro.kernels.pack.kernel import gather_rows_pallas, scatter_rows_pallas
 from repro.kernels.pack.ref import gather_rows_ref, scatter_rows_ref
 
 
 def _collapse(a: jax.Array):
-    """(R, *event) -> (R, D) with D lane-padded; returns (rows, event, D)."""
+    """(R, *event) -> (R, D); returns (rows, event_shape, D)."""
     event_shape = a.shape[1:]
     D = math.prod(event_shape) if event_shape else 1
     return a.reshape(a.shape[0], D), event_shape, D
@@ -39,12 +35,9 @@ def gather_rows(
     interpret = resolve_interpret(interpret)
     src2, event_shape, D = _collapse(src)
     M = idx.shape[0]
-    pad_m = (-M) % ROW_BLK
     src2 = pad_dim(src2, (-D) % LANE, axis=1)
-    # padding rows re-read row 0 and are sliced off below
-    idx2 = pad_dim(idx.astype(jnp.int32), pad_m, axis=0)
-    out = gather_rows_pallas(src2, idx2, interpret=interpret)
-    return out[:M, :D].reshape((M,) + event_shape)
+    out = gather_rows_pallas(src2, idx.astype(jnp.int32), interpret=interpret)
+    return out[:, :D].reshape((M,) + event_shape)
 
 
 def scatter_rows(
@@ -60,10 +53,7 @@ def scatter_rows(
         return scatter_rows_ref(vals, idx, num_rows)
     interpret = resolve_interpret(interpret)
     vals2, event_shape, D = _collapse(vals)
-    M = idx.shape[0]
-    pad_m = (-M) % ROW_BLK
     vals2 = pad_dim(vals2, (-D) % LANE, axis=1)
-    # padding rows target num_rows (out of range) and are dropped in-kernel
-    idx2 = pad_dim(idx.astype(jnp.int32), pad_m, axis=0, value=num_rows)
-    out = scatter_rows_pallas(vals2, idx2, num_rows, interpret=interpret)
+    out = scatter_rows_pallas(vals2, idx.astype(jnp.int32), num_rows,
+                              interpret=interpret)
     return out[:, :D].reshape((num_rows,) + event_shape)

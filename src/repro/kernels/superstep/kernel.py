@@ -7,24 +7,24 @@ gather of live points, the five scalar-window gathers, the GRS
 accept/reflect pass, and the two commit scatters — used to be seven separate
 XLA programs per scan iteration.  The two kernels here collapse them to two:
 
-  ``_fused_gather_kernel``   the pack side: ONE program gathers the y_prev /
+  ``fused_gather_pallas``    the pack side: ONE program gathers the y_prev /
       xi / m_hat event rows AND the packed scalar table (t, u, A, B, sigma
-      stacked as lanes of one (N, C) table) for every packed position.  All
-      four source tables sit whole in VMEM (they are the slot batch's
-      speculation window — small by construction); each grid step copies
-      ROW_BLK packed rows out of each.
+      stacked as lanes of one (N, C) table) for every packed position.  The
+      tables stay in HBM; each grid step DMAs one packed row out of each by
+      its scalar-prefetched index (``repro.kernels.pack.kernel``).
 
   ``_fused_commit_kernel``   the verify/commit side: ONE program computes
       the target mean m = A * y + B * g in-register, runs the full GRS math
-      (bit-compatible with ``repro.kernels.grs.kernel._grs_kernel``), and
-      scatters the per-row sample z and accept bit straight into the
-      (num_rows, ...) slot-window tables — the commit scatter rides the same
-      pass instead of a separate program.  Out-of-range rows (the pack's
-      padding lanes) are dropped by predication, unwritten rows stay zero.
+      (``repro.kernels.grs.kernel.grs_select``, the same accept/reflect code
+      as the GRS kernel), and writes the per-row sample z and accept flag
+      straight into the (num_rows, ...) slot-window tables — the commit
+      scatter rides the same pass instead of a separate program.  The tables
+      are zero buffers aliased as the outputs, so unwritten rows stay zero;
+      out-of-range rows (the pack's padding lanes) land on one extra drop
+      row that is sliced off.
 
-Layout contracts match kernels/grs and kernels/pack: rows blocked by
-ROW_BLK, feature axes lane-padded (128) by ops.py, TPU grid steps sequential
-(the scatter zero-init on step 0 is safe).
+Layout contracts match kernels/pack: one packed row per grid step, feature
+axes lane-padded (128) by ops.py and viewed as (D // 128, 128) tiles.
 """
 
 from __future__ import annotations
@@ -34,98 +34,47 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-ROW_BLK = 8
-_EPS = 1e-20
+from repro.kernels.grs.kernel import grs_select
+from repro.kernels.pack.kernel import as_row_tiles, gather_tables, row_spec
 
-
-def _fused_gather_kernel(idx_ref, y_ref, xi_ref, mh_ref, sc_ref,
-                         oy_ref, oxi_ref, omh_ref, osc_ref):
-    for r in range(ROW_BLK):
-        row = idx_ref[r, 0]
-        oy_ref[r, :] = y_ref[row, :]
-        oxi_ref[r, :] = xi_ref[row, :]
-        omh_ref[r, :] = mh_ref[row, :]
-        osc_ref[r, :] = sc_ref[row, :]
+# lanes of the per-row scalar tile handed to the verify/commit kernel
+SCALAR_LANES = ("u", "sigma", "A", "B")
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def fused_gather_pallas(y, xi, mh, sc, idx, interpret: bool = False):
     """y, xi, mh: (N, D); sc: (N, C); idx: (M,) int32 in [0, N).
 
-    M % ROW_BLK == 0, D % 128 == 0, C % 128 == 0.  Returns the four packed
-    row sets ((M, D) x 3, (M, C)) in one kernel launch.
+    D % 128 == 0, C % 128 == 0.  Returns the four packed row sets
+    ((M, D) x 3, (M, C)) in one kernel launch.
     """
-    N, D = y.shape
-    C = sc.shape[1]
-    (M,) = idx.shape
-    assert M % ROW_BLK == 0, (M, ROW_BLK)
-    grid = (M // ROW_BLK,)
-    table = lambda d: pl.BlockSpec((N, d), lambda i: (0, 0))  # noqa: E731
-    packed = lambda d: pl.BlockSpec((ROW_BLK, d), lambda i: (i, 0))  # noqa: E731
-    return pl.pallas_call(
-        _fused_gather_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((ROW_BLK, 1), lambda i: (i, 0)),  # idx block
-            table(D), table(D), table(D), table(C),
-        ],
-        out_specs=[packed(D), packed(D), packed(D), packed(C)],
-        out_shape=[
-            jax.ShapeDtypeStruct((M, D), y.dtype),
-            jax.ShapeDtypeStruct((M, D), xi.dtype),
-            jax.ShapeDtypeStruct((M, D), mh.dtype),
-            jax.ShapeDtypeStruct((M, C), sc.dtype),
-        ],
-        interpret=interpret,
-    )(idx[:, None], y, xi, mh, sc)
+    return tuple(gather_tables([y, xi, mh, sc], idx, interpret=interpret))
 
 
-def _fused_commit_kernel(idx_ref, u_ref, sig_ref, a_ref, b_ref,
-                         y_ref, g_ref, xi_ref, mh_ref,
-                         z_ref, acc_ref, *, num_rows: int):
-    @pl.when(pl.program_id(0) == 0)
-    def _():
-        z_ref[...] = jnp.zeros_like(z_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+def _total(x):
+    """Sum of a (T, 128) tile as a (1, 1) value: lanes, then sublanes."""
+    return jnp.sum(jnp.sum(x, axis=1, keepdims=True), axis=0, keepdims=True)
 
-    y = y_ref[...].astype(jnp.float32)  # (R, D)
+
+def _fused_commit_kernel(idx_ref, sc_ref, y_ref, g_ref, xi_ref, mh_ref,
+                         z0_ref, a0_ref, z_ref, acc_ref):
+    del idx_ref, z0_ref, a0_ref  # the zero tables ARE z_ref/acc_ref (aliased)
+    sc = sc_ref[...].astype(jnp.float32)  # (1, 128)
+    u, sig, A, B = (sc[:, k:k + 1] for k in range(len(SCALAR_LANES)))
+    y = y_ref[...].astype(jnp.float32)  # (T, 128)
     g = g_ref[...].astype(jnp.float32)
     xi = xi_ref[...].astype(jnp.float32)
     mh = mh_ref[...].astype(jnp.float32)
-    u = u_ref[...].astype(jnp.float32)  # (R, 1)
-    sig = sig_ref[...].astype(jnp.float32)
-    A = a_ref[...].astype(jnp.float32)
-    B = b_ref[...].astype(jnp.float32)
 
     # the verifier's target mean, fused in-register — same affine form the
     # packed round materializes between its model call and the GRS pass
     mt = A * y + B * g
-
-    # GRS math bit-compatible with kernels/grs/kernel._grs_kernel
     v = mh - mt
-    vnorm2 = jnp.sum(v * v, axis=1, keepdims=True)  # (R, 1)
-    vdotxi = jnp.sum(v * xi, axis=1, keepdims=True)
-
-    safe_sig = jnp.where(sig > 0, sig, 1.0)
-    log_ratio = -(vdotxi / safe_sig + vnorm2 / (2.0 * safe_sig * safe_sig))
-    accept = jnp.log(jnp.maximum(u, _EPS)) <= jnp.minimum(log_ratio, 0.0)
-    accept = jnp.where(sig > 0, accept, vnorm2 <= 0.0)  # (R, 1)
-
-    safe_vn = jnp.where(vnorm2 > 0, vnorm2, 1.0)
-    coef = 2.0 * vdotxi / safe_vn  # (R, 1)
-    xi_refl = jnp.where(vnorm2 > 0, xi - coef * v, xi)
-
-    z = jnp.where(accept, mh + sig * xi, mt + sig * xi_refl)
-    acc = accept.astype(jnp.int32)
-
-    for r in range(ROW_BLK):
-        row = idx_ref[r, 0]
-
-        @pl.when(row < num_rows)
-        def _():
-            z_ref[row, :] = z[r, :].astype(z_ref.dtype)
-            acc_ref[row, :] = acc[r, :]
+    z, accept = grs_select(u, sig, xi, mh, mt, _total(v * v), _total(v * xi))
+    z_ref[...] = z.astype(z_ref.dtype)
+    acc_ref[...] = jnp.broadcast_to(accept, acc_ref.shape).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("num_rows", "interpret"))
@@ -133,33 +82,34 @@ def fused_verify_commit_pallas(u, sigma, A, B, y, g, xi, mh, idx,
                                num_rows: int, interpret: bool = False):
     """u, sigma, A, B: (M,); y, g, xi, mh: (M, D); idx: (M,) int32.
 
-    M % ROW_BLK == 0, D % 128 == 0.  Returns (z_table: (num_rows, D),
-    accept_table: (num_rows,) int32): the GRS outputs scattered to their
-    slot-window rows; idx[p] >= num_rows drops row p, unwritten rows zero.
-    In-range indices must be unique (the pack maps guarantee it).
+    D % 128 == 0.  Returns (z_table: (num_rows, D), accept_table:
+    (num_rows,) int32): the GRS outputs scattered to their slot-window rows;
+    idx[p] >= num_rows drops row p, unwritten rows zero.  In-range indices
+    must be unique (the pack maps guarantee it).
     """
-    M, D = y.shape
-    assert M % ROW_BLK == 0, (M, ROW_BLK)
-    grid = (M // ROW_BLK,)
-    row_spec = pl.BlockSpec((ROW_BLK, D), lambda i: (i, 0))
-    scalar_spec = pl.BlockSpec((ROW_BLK, 1), lambda i: (i, 0))
+    (M,) = idx.shape
+    lanes = jnp.stack([u, sigma, A, B], axis=-1).astype(jnp.float32)
+    scal = jnp.zeros((M, 1, 128), jnp.float32).at[:, 0, :lanes.shape[1]].set(
+        lanes)
+    rows = [as_row_tiles(a) for a in (y, g, xi, mh)]
+    z0 = jnp.zeros((num_rows + 1,) + rows[0].shape[1:], xi.dtype)
+    a0 = jnp.zeros((num_rows + 1, 1, 128), jnp.int32)
+    packed = lambda p, idx: p  # noqa: E731
+    target = lambda p, idx: jnp.minimum(idx[p], num_rows)  # noqa: E731
     z, acc = pl.pallas_call(
-        functools.partial(_fused_commit_kernel, num_rows=num_rows),
-        grid=grid,
-        in_specs=[
-            scalar_spec,  # idx
-            scalar_spec, scalar_spec, scalar_spec, scalar_spec,  # u/sig/A/B
-            row_spec, row_spec, row_spec, row_spec,  # y/g/xi/mh
-        ],
-        out_specs=[
-            pl.BlockSpec((num_rows, D), lambda i: (0, 0)),
-            pl.BlockSpec((num_rows, 1), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((num_rows, D), xi.dtype),
-            jax.ShapeDtypeStruct((num_rows, 1), jnp.int32),
-        ],
+        _fused_commit_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(M,),
+            in_specs=[row_spec(scal, packed)]
+            + [row_spec(r, packed) for r in rows]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * 2,
+            out_specs=[row_spec(z0, target), row_spec(a0, target)],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(z0.shape, z0.dtype),
+                   jax.ShapeDtypeStruct(a0.shape, a0.dtype)],
+        # operands: (idx, scal, y, g, xi, mh, z0, a0)
+        input_output_aliases={6: 0, 7: 1},
         interpret=interpret,
-    )(idx[:, None], u[:, None], sigma[:, None], A[:, None], B[:, None],
-      y, g, xi, mh)
-    return z, acc[:, 0]
+    )(idx, scal, *rows, z0, a0)
+    return z[:num_rows].reshape(num_rows, -1), acc[:num_rows, 0, 0]

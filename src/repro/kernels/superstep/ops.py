@@ -14,7 +14,6 @@ import jax.numpy as jnp
 from repro.kernels._backend import resolve_interpret
 from repro.kernels._padding import LANE, pad_dim
 from repro.kernels.superstep.kernel import (
-    ROW_BLK,
     fused_gather_pallas,
     fused_verify_commit_pallas,
 )
@@ -56,17 +55,14 @@ def fused_gather(
     C = scal_tbl.shape[1]
     M = idx.shape[0]
     pad_d = (-D) % LANE
-    pad_c = (-C) % LANE
-    pad_m = (-M) % ROW_BLK
     y2 = pad_dim(y2, pad_d, axis=1)
     xi2 = pad_dim(xi2, pad_d, axis=1)
     mh2 = pad_dim(mh2, pad_d, axis=1)
-    sc2 = pad_dim(scal_tbl, pad_c, axis=1)
-    idx2 = pad_dim(idx.astype(jnp.int32), pad_m, axis=0)
+    sc2 = pad_dim(scal_tbl, (-C) % LANE, axis=1)
     oy, oxi, omh, osc = fused_gather_pallas(
-        y2, xi2, mh2, sc2, idx2, interpret=interpret)
-    unpack = lambda o: o[:M, :D].reshape((M,) + event_shape)  # noqa: E731
-    return unpack(oy), unpack(oxi), unpack(omh), osc[:M, :C]
+        y2, xi2, mh2, sc2, idx.astype(jnp.int32), interpret=interpret)
+    unpack = lambda o: o[:, :D].reshape((M,) + event_shape)  # noqa: E731
+    return unpack(oy), unpack(oxi), unpack(omh), osc[:, :C]
 
 
 def fused_verify_commit(
@@ -99,21 +95,10 @@ def fused_verify_commit(
     g2, _, _ = _collapse(g)
     xi2, _, _ = _collapse(xi)
     mh2, _, _ = _collapse(mh)
-    M = idx.shape[0]
     pad_d = (-D) % LANE
-    pad_m = (-M) % ROW_BLK
-    y2, g2, xi2, mh2 = (
-        pad_dim(pad_dim(a, pad_d, axis=1), pad_m, axis=0)
-        for a in (y2, g2, xi2, mh2)
-    )
-    u2 = pad_dim(u, pad_m, axis=0)
-    A2 = pad_dim(A, pad_m, axis=0)
-    B2 = pad_dim(B, pad_m, axis=0)
-    s2 = pad_dim(sigma, pad_m, axis=0, value=1.0)
-    # padding rows target num_rows (out of range) and are dropped in-kernel
-    idx2 = pad_dim(idx.astype(jnp.int32), pad_m, axis=0, value=num_rows)
+    y2, g2, xi2, mh2 = (pad_dim(a, pad_d, axis=1) for a in (y2, g2, xi2, mh2))
     z, acc = fused_verify_commit_pallas(
-        u2, s2, A2, B2, y2, g2, xi2, mh2, idx2, num_rows,
+        u, sigma, A, B, y2, g2, xi2, mh2, idx.astype(jnp.int32), num_rows,
         interpret=interpret)
     z_tbl = z[:, :D].reshape((num_rows,) + event_shape)
     return z_tbl, acc.astype(bool)

@@ -1,7 +1,13 @@
 """Production pjit ASD server: batched diffusion sampling on a mesh.
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
-    PYTHONPATH=src python -m repro.launch.serve --mesh 2x4 --chains 8 --theta 8
+    PYTHONPATH=src python -m repro.launch.serve --chains 8 --theta 8
+
+``--mesh DATAxMODEL`` defaults to every visible device on the data axis
+(``1x1`` on one chip); a mesh larger than the device count is refused.
+Off the chip, ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
+simulates devices.  Compiled programs persist in JAX's compilation cache:
+``JAX_COMPILATION_CACHE_DIR`` if set, else ``.jax_cache/`` at the checkout
+root (``configure_compile_cache``).
 
 Two serving modes:
 
@@ -14,9 +20,9 @@ Two serving modes:
                        chains retire at round boundaries and their slots are
                        refilled from the request queue (repro/serving).
 
-Both are the TPU-native form of the paper's multi-GPU parallel verification
-(DESIGN.md §2): the per-round model call is a (slots*theta)-point forward,
-data-parallel over the mesh.
+Both are the TPU-native form of the paper's multi-GPU parallel verification:
+the per-round model call is a (slots*theta)-point forward, data-parallel
+over the mesh.
 
 Speculation control and scheduling are pluggable:
 
@@ -118,7 +124,10 @@ from __future__ import annotations
 import argparse
 import json
 import logging
+import os
+import pathlib
 import time
+from types import SimpleNamespace
 import urllib.error
 import urllib.request
 
@@ -168,10 +177,50 @@ from repro.serving.sharded import ShardedASDEngine
 log = logging.getLogger("repro.serving.serve")
 
 
-def _build(args):
-    dims = tuple(int(x) for x in args.mesh.split("x"))
+# the checkout root: src/repro/launch/serve.py -> three levels up
+_CHECKOUT_ROOT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def configure_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins and JAX reads it itself.
+    Otherwise the cache goes to ``.jax_cache/`` at the checkout root: a
+    fixed path, because the path is part of what a later run must find.
+    Called by the entry points only, never at import."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(_CHECKOUT_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def make_mesh(spec: str | None) -> Mesh:
+    """The serving mesh from ``--mesh`` (DATAxMODEL or PODxDATAxMODEL).
+
+    ``None`` puts every visible device on the data axis.  A mesh that needs
+    more devices than JAX sees is refused with the device count and
+    platform, instead of failing inside a reshape."""
+    devices = jax.devices()
+    spec = spec or f"{len(devices)}x1"
+    dims = tuple(int(x) for x in spec.split("x"))
     names = ("data", "model") if len(dims) == 2 else ("pod", "data", "model")
-    mesh = Mesh(np.asarray(jax.devices()[: int(np.prod(dims))]).reshape(dims), names)
+    need = int(np.prod(dims))
+    if need > len(devices):
+        raise SystemExit(
+            f"--mesh {spec} needs {need} devices, but JAX sees "
+            f"{len(devices)} {devices[0].platform} device(s); pass a mesh "
+            f"of at most {len(devices)} devices, e.g. --mesh "
+            f"{len(devices)}x1, or omit --mesh")
+    if need < len(devices):
+        log.warning("--mesh %s uses %d of the %d %s devices", spec, need,
+                    len(devices), devices[0].platform)
+    return Mesh(np.asarray(devices[:need]).reshape(dims), names)
+
+
+def _build(args):
+    mesh = make_mesh(args.mesh)
 
     dc = get_denoiser_config(args.model)
     boxed = jax.eval_shape(lambda k: denoiser_init(k, dc), jax.random.PRNGKey(0))
@@ -230,8 +279,15 @@ def _profile_supersteps(eng, args, slots):
           f"{args.profile_dir} (view with tensorboard or xprof)")
 
 
-def run_continuous(args):
-    mesh, dc, params = _build(args)
+def make_requests(n: int) -> list:
+    """The CLI's request set: request i carries key PRNGKey(1000 + i)."""
+    return [Request(i, key=jax.random.PRNGKey(1000 + i)) for i in range(n)]
+
+
+def build_engine(args, mesh, dc, params):
+    """The continuous serving engine the CLI flags describe, over ``params``
+    (a pytree from ``_build``).  Returns ``(engine, layout)``; ``layout``
+    carries the resolved slot, budget and model-parallel settings."""
     sched = ddpm_schedule(args.K)
     batch_world = int(np.prod([mesh.shape[a] for a in mesh.axis_names
                                if a in ("pod", "data")]))
@@ -353,6 +409,17 @@ def run_continuous(args):
             state_sharding=chain_state_shardings(mesh),
             **common,
         )
+    layout = SimpleNamespace(slots=slots, slots_local=slots_local,
+                             budget=budget, mp_total=mp_total, sp=sp, ep=ep,
+                             tracer=tracer)
+    return eng, layout
+
+
+def run_continuous(args):
+    mesh, dc, params = _build(args)
+    eng, layout = build_engine(args, mesh, dc, params)
+    slots, slots_local, budget = layout.slots, layout.slots_local, layout.budget
+    mp_total, sp, ep, tracer = layout.mp_total, layout.sp, layout.ep, layout.tracer
     server = None
     if args.metrics_port >= 0:
         registry = MetricsRegistry()
@@ -363,7 +430,7 @@ def run_continuous(args):
         print(f"[metrics] serving /metrics and /healthz at {server.url}")
     if args.profile_supersteps > 0:
         _profile_supersteps(eng, args, slots)
-    reqs = [Request(i, key=jax.random.PRNGKey(1000 + i)) for i in range(args.chains)]
+    reqs = make_requests(args.chains)
     t0 = time.perf_counter()
     out = eng.serve(reqs)
     dt = time.perf_counter() - t0
@@ -438,10 +505,12 @@ def run_continuous(args):
               f"(load in Perfetto / chrome://tracing)")
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="paper-diffusion-policy")
-    ap.add_argument("--mesh", default="2x4")
+    ap.add_argument("--mesh", default=None,
+                    help="DATAxMODEL (or PODxDATAxMODEL) device mesh; "
+                         "default: every visible device on the data axis")
     ap.add_argument("--engine", default="continuous",
                     choices=("continuous", "fused"))
     ap.add_argument("--chains", type=int, default=8)
@@ -548,7 +617,12 @@ def main():
     ap.add_argument("--log-level", default="info",
                     choices=("debug", "info", "warning", "error"),
                     help="repro.serving.* logger threshold")
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = build_parser().parse_args()
+    configure_compile_cache()
     # root stays at WARNING (jax's own loggers are chatty at DEBUG); the
     # flag governs the repro.serving.* hierarchy only
     logging.basicConfig(format="%(levelname)s %(name)s: %(message)s")

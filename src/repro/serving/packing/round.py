@@ -583,9 +583,6 @@ def sharded_packed_superstep(
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.sharding import get_shard_map
-
-    shard_map = get_shard_map()
     impl = "fused" if fused_round else round_impl
 
     def one_shard(p, st, w, cond, b):
@@ -609,21 +606,21 @@ def sharded_packed_superstep(
     pspec = rep if param_specs is None else param_specs
     if budget_data is None:
         if conds is None:
-            fn = shard_map(
+            fn = jax.jit(jax.shard_map(
                 lambda p, st, w: one_shard(p, st, w, None, None), mesh=mesh,
-                in_specs=(pspec, sh, sh), out_specs=sh, check_rep=False)
+                in_specs=(pspec, sh, sh), out_specs=sh, check_vma=False))
             return fn(params, states, weights)
-        fn = shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda p, st, w, c: one_shard(p, st, w, c, None), mesh=mesh,
-            in_specs=(pspec, sh, sh, sh), out_specs=sh, check_rep=False)
+            in_specs=(pspec, sh, sh, sh), out_specs=sh, check_vma=False))
         return fn(params, states, weights, conds)
     budget_data = jnp.asarray(budget_data, jnp.int32)
     if conds is None:
-        fn = shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda p, st, w, b: one_shard(p, st, w, None, b), mesh=mesh,
-            in_specs=(pspec, sh, sh, sh), out_specs=sh, check_rep=False)
+            in_specs=(pspec, sh, sh, sh), out_specs=sh, check_vma=False))
         return fn(params, states, weights, budget_data)
-    fn = shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda p, st, w, c, b: one_shard(p, st, w, c, b), mesh=mesh,
-        in_specs=(pspec, sh, sh, sh, sh), out_specs=sh, check_rep=False)
+        in_specs=(pspec, sh, sh, sh, sh), out_specs=sh, check_vma=False))
     return fn(params, states, weights, conds, budget_data)

@@ -383,11 +383,8 @@ class ShardedASDEngine:
 
         from repro.core.asd import chain_sample
 
-        from repro.distributed.sharding import get_shard_map
-
         w0 = self.workers[0]
         K, keep = w0.schedule.K, w0.keep_trajectory
-        shard_map = get_shard_map()
 
         def one_shard(st, cond, w, p, b):
             # inside shard_map the shard axis has local size 1: peel it,
@@ -416,34 +413,34 @@ class ShardedASDEngine:
         has_conds = self._conds is not None
         if as_data:
             if has_conds:
-                body = shard_map(
+                body = jax.shard_map(
                     lambda st, c, w, p, b: one_shard(st, c, w, p, b),
                     mesh=self._mesh, in_specs=(sh, sh, sh, pp, sh),
-                    out_specs=(sh, sh, sh), check_rep=False)
+                    out_specs=(sh, sh, sh), check_vma=False)
 
                 def fused(states, conds, p, weights, budgets):
                     return body(states, conds, weights, p, budgets)
             else:
-                body = shard_map(
+                body = jax.shard_map(
                     lambda st, w, p, b: one_shard(st, None, w, p, b),
                     mesh=self._mesh, in_specs=(sh, sh, pp, sh),
-                    out_specs=(sh, sh, sh), check_rep=False)
+                    out_specs=(sh, sh, sh), check_vma=False)
 
                 def fused(states, conds, p, weights, budgets):
                     return body(states, weights, p, budgets)
         elif has_conds:
-            body = shard_map(
+            body = jax.shard_map(
                 lambda st, c, w, p: one_shard(st, c, w, p, None),
                 mesh=self._mesh, in_specs=(sh, sh, sh, pp),
-                out_specs=(sh, sh, sh), check_rep=False)
+                out_specs=(sh, sh, sh), check_vma=False)
 
             def fused(states, conds, p, weights):
                 return body(states, conds, weights, p)
         else:
-            body = shard_map(
+            body = jax.shard_map(
                 lambda st, w, p: one_shard(st, None, w, p, None),
                 mesh=self._mesh, in_specs=(sh, sh, pp),
-                out_specs=(sh, sh, sh), check_rep=False)
+                out_specs=(sh, sh, sh), check_vma=False)
 
             def fused(states, conds, p, weights):
                 return body(states, weights, p)

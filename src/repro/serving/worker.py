@@ -285,7 +285,7 @@ class ShardWorker:
         # (the aliased input buffer must be finalized before the call
         # returns), so the double-buffered loops lose their overlap and the
         # dispatch timer absorbs the whole device execution — default it off
-        # there, on everywhere else (TPU/GPU dispatch stays async)
+        # there, on everywhere else (TPU dispatch stays async)
         self._donate = (
             bool(donate) if donate is not None
             else jax.default_backend() != "cpu")
@@ -467,18 +467,16 @@ class ShardWorker:
                 # everything else is replicated across the group and stays
                 # bitwise lockstep — the only cross-device data flow is the
                 # model fn's in-program psums, whose reduction order is fixed
-                # by the program, so replicated out_specs (check_rep=False)
+                # by the program, so replicated out_specs (check_vma=False)
                 # are sound and the superstep is still ONE dispatch.
-                from repro.distributed.sharding import get_shard_map
-
                 rep = P()
                 n_in = 5 if budget == "data" else 4
                 in_specs = [rep] * n_in
                 in_specs[2] = self._param_specs
-                _superstep = get_shard_map()(
+                _superstep = jax.shard_map(
                     _superstep, mesh=self._model_mesh,
                     in_specs=tuple(in_specs), out_specs=rep,
-                    check_rep=False)
+                    check_vma=False)
             return jax.jit(_superstep, donate_argnums=donate)
 
         self._make_superstep = _make_superstep

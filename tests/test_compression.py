@@ -44,19 +44,11 @@ PSUM_SCRIPT = textwrap.dedent(
     from repro.distributed.compression import int8_psum_tree
 
     mesh = Mesh(np.asarray(jax.devices()).reshape(2, 2, 2), ("pod", "data", "model"))
-    if hasattr(jax, "shard_map"):
-        fn = jax.shard_map(
-            lambda g: int8_psum_tree(g, "pod"),
-            mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
-            check_vma=False, axis_names={"pod"},
-        )
-    else:  # older jax: experimental API, replication check instead of vma
-        from jax.experimental.shard_map import shard_map
-        fn = shard_map(
-            lambda g: int8_psum_tree(g, "pod"),
-            mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
-            check_rep=False,
-        )
+    fn = jax.shard_map(
+        lambda g: int8_psum_tree(g, "pod"),
+        mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
+        check_vma=False, axis_names={"pod"},
+    )
     x = jnp.arange(16, dtype=jnp.float32).reshape(2, 8)
     y = np.asarray(jax.jit(fn)(x))
     expect = np.tile((np.arange(8) + np.arange(8, 16)) / 2.0, (2, 1))

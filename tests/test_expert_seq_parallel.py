@@ -38,7 +38,6 @@ from repro.configs.registry import (
 from repro.core.schedules import ddpm as ddpm_schedule
 from repro.distributed.sharding import (
     EP_VERIFY_SIGS,
-    get_shard_map,
     measure_collective_seconds,
     measure_collective_seconds_by_kind,
     mp_param_pspecs,
@@ -271,9 +270,9 @@ def test_moe_ep_matches_dense_and_is_deterministic(moe_model):
         y, aux = moe_lib.moe_apply(p, x, cfg, ep_axis="model")
         return y, aux["moe_aux_loss"]
 
-    f = jax.jit(get_shard_map()(
+    f = jax.jit(jax.shard_map(
         ep_fn, mesh=mesh, in_specs=(pspecs, P()), out_specs=(P(), P()),
-        check_rep=False))
+        check_vma=False))
     y1, aux1 = f(placed, x)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y_ref),
                                rtol=1e-5, atol=1e-5)
@@ -293,11 +292,11 @@ def test_moe_ep_matches_dense_and_is_deterministic(moe_model):
 
     # seq-sharded (Ulysses-composed) variant: x enters pre-sliced, the
     # output stays local — no slice, no combine psum
-    g = jax.jit(get_shard_map()(
+    g = jax.jit(jax.shard_map(
         lambda p, x: moe_lib.moe_apply(p, x, cfg, ep_axis="model",
                                        seq_sharded=True)[0],
         mesh=mesh, in_specs=(pspecs, P(None, "model", None)),
-        out_specs=P(None, "model", None), check_rep=False))
+        out_specs=P(None, "model", None), check_vma=False))
     y_sp = g(placed, x)
     np.testing.assert_allclose(np.asarray(y_sp), np.asarray(y_ref),
                                rtol=1e-5, atol=1e-5)
@@ -314,19 +313,19 @@ def test_sp_denoiser_matches_replicated(sp_model):
     ref = denoiser_fwd(params, t, y, dc)
     mesh = Mesh(np.asarray(jax.devices()[:2]), ("model",))
     rep = jax.tree_util.tree_map(lambda _: P(), params)
-    f = jax.jit(get_shard_map()(
+    f = jax.jit(jax.shard_map(
         lambda p, t, y: denoiser_fwd(p, t, y, dc, sp_axis="model", sp_size=2),
         mesh=mesh, in_specs=(rep, P(), P()), out_specs=P(),
-        check_rep=False))
+        check_vma=False))
     out1 = f(params, t, y)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
     out2 = f(params, t, y)
     np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
-    g = jax.jit(get_shard_map()(
+    g = jax.jit(jax.shard_map(
         lambda p, t, y: denoiser_fwd(p, t, y, dc, sp_axis=None, sp_size=1),
         mesh=mesh, in_specs=(rep, P(), P()), out_specs=P(),
-        check_rep=False))
+        check_vma=False))
     np.testing.assert_array_equal(np.asarray(g(params, t, y)),
                                   np.asarray(ref))
 
@@ -339,6 +338,10 @@ def test_measure_collective_kinds():
     mesh = serving_mesh(1, 2)
     assert measure_collective_seconds(mesh, [4096], kind="psum") > 0.0
     assert measure_collective_seconds(mesh, [4096], kind="all_to_all") > 0.0
+    # a layer stack's schedule: repeated sizes share one looped buffer
+    for kind in ("psum", "all_to_all"):
+        assert measure_collective_seconds(
+            mesh, [4096] * 6 + [8192] * 2, kind=kind) > 0.0
     with pytest.raises(ValueError):
         measure_collective_seconds(mesh, [4096], kind="all_gather")
     by_kind = measure_collective_seconds_by_kind(

@@ -1,11 +1,8 @@
 """Kernel backend autodetection (repro.kernels._backend): the one shared
 ``resolve_interpret`` every ops wrapper consults.
 
-Regression: each ops.py used to decide ``interpret = not on_tpu()`` on its
-own, which silently sent GPU runs down the pure-Python interpret path and
-offered no override and no log line.  The contract now: explicit argument >
-``REPRO_PALLAS_INTERPRET`` env > backend default (TPU/GPU-with-Triton
-compiled, everything else interpret), logged once per backend.
+The contract: explicit argument > ``REPRO_PALLAS_INTERPRET`` env > backend
+default (TPU compiled, everything else interpret), logged once per backend.
 """
 
 import logging
@@ -46,13 +43,11 @@ def test_backend_defaults(monkeypatch):
     chosen = resolve_interpret()
     if jax.default_backend() == "cpu":
         assert chosen is True
-    # forced backend views (resolve_interpret reads jax.default_backend)
+    # forced backend views (resolve_interpret reads jax.default_backend):
+    # only the TPU compiles, every other backend interprets
     monkeypatch.setattr(_backend.jax, "default_backend", lambda: "tpu")
     assert resolve_interpret() is False
     monkeypatch.setattr(_backend.jax, "default_backend", lambda: "gpu")
-    monkeypatch.setattr(_backend, "_gpu_triton_available", lambda: True)
-    assert resolve_interpret() is False
-    monkeypatch.setattr(_backend, "_gpu_triton_available", lambda: False)
     assert resolve_interpret() is True
 
 

@@ -258,8 +258,10 @@ def test_superstep_compiles_once_per_R(sl_model2, sched_tiny):
 def test_donation_no_stale_buffers_across_waves(sl_model2, sched_tiny):
     """The superstep donates the slot-state pytree.  After a wave's final
     harvest the engine must dispatch cleanly again on the surviving buffers
-    — three back-to-back waves, each bit-identical to a fresh engine."""
-    eng = _engine(sl_model2, sched_tiny, rounds_per_sync=4)
+    — three back-to-back waves, each bit-identical to a fresh engine.
+    Donation is forced on: it defaults off on the CPU backend."""
+    eng = _engine(sl_model2, sched_tiny, rounds_per_sync=4, donate=True)
+    assert eng._donate
     for wave, (n, seed0) in enumerate([(6, 100), (3, 400), (9, 500)]):
         ref = _engine(sl_model2, sched_tiny).serve(_requests(n, seed0))
         out = eng.serve(_requests(n, seed0))
