@@ -265,6 +265,7 @@ def _window(arr, start, length):
     return jax.lax.dynamic_slice_in_dim(arr, start, length, axis=0)
 
 
+@jax.named_scope("asd/propose")
 def plan_round(
     model_fn: ModelFn,
     schedule: Schedule,
@@ -390,6 +391,7 @@ def plan_round(
     )
 
 
+@jax.named_scope("asd/commit")
 def commit_round(
     schedule: Schedule,
     st: ASDChainState,
@@ -568,33 +570,35 @@ def asd_round(
     y_prev = plan.y_prev
 
     # --- 3. ONE batched parallel round (line 11)
-    if eager_head:
-        # the head slot sits at the END of the LIVE window: on a full accept
-        # the chain lands on y_props[theta_live - 1], so this evaluation IS
-        # the next round's proposal call
-        y_head = jax.lax.dynamic_index_in_dim(
-            plan.y_props, theta_live - 1, axis=0, keepdims=True
-        )
-        pts = jnp.concatenate([y_prev, y_head], axis=0)
-        ts = jnp.concatenate([t_w, plan.t_w1[theta_live][None]], axis=0)
-        g_all = model_fn(ts, pts)
-        g_par, g_head = g_all[:-1], g_all[-1]
-    else:
-        g_par = model_fn(t_w, y_prev)
-        g_head = None
-    m_tgt = bcast_right(plan.A_w, ev_ndim + 1) * y_prev + bcast_right(
-        plan.B_w, ev_ndim + 1
-    ) * g_par
+    with jax.named_scope("asd/verify"):
+        if eager_head:
+            # the head slot sits at the END of the LIVE window: on a full
+            # accept the chain lands on y_props[theta_live - 1], so this
+            # evaluation IS the next round's proposal call
+            y_head = jax.lax.dynamic_index_in_dim(
+                plan.y_props, theta_live - 1, axis=0, keepdims=True
+            )
+            pts = jnp.concatenate([y_prev, y_head], axis=0)
+            ts = jnp.concatenate([t_w, plan.t_w1[theta_live][None]], axis=0)
+            g_all = model_fn(ts, pts)
+            g_par, g_head = g_all[:-1], g_all[-1]
+        else:
+            g_par = model_fn(t_w, y_prev)
+            g_head = None
 
     # --- 4. Verifier (Alg 2) + windowed commit
-    if grs_impl == "kernel":
-        from repro.kernels.grs.ops import grs as grs_k
+    with jax.named_scope("asd/grs"):
+        m_tgt = bcast_right(plan.A_w, ev_ndim + 1) * y_prev + bcast_right(
+            plan.B_w, ev_ndim + 1
+        ) * g_par
+        if grs_impl == "kernel":
+            from repro.kernels.grs.ops import grs as grs_k
 
-        z, acc = grs_k(plan.u_w, plan.xi_w, plan.m_hats, m_tgt, plan.sig_w,
-                       event_ndim=ev_ndim)
-    else:
-        z, acc = grs(plan.u_w, plan.xi_w, plan.m_hats, m_tgt, plan.sig_w,
-                     event_ndim=ev_ndim)
+            z, acc = grs_k(plan.u_w, plan.xi_w, plan.m_hats, m_tgt,
+                           plan.sig_w, event_ndim=ev_ndim)
+        else:
+            z, acc = grs(plan.u_w, plan.xi_w, plan.m_hats, m_tgt, plan.sig_w,
+                         event_ndim=ev_ndim)
     return commit_round(
         schedule, st, plan, z, acc, theta_live, g_head, theta,
         eager_head, keep_trajectory, controller,
@@ -632,37 +636,40 @@ def _branched_verify_select(
 
     y_prev_f = plan.y_prev_b.reshape((B * theta,) + ev_shape)
     ts_f = jnp.tile(t_w, B)
-    if eager_head:
-        # one head point PER BRANCH at the end of the live window: whichever
-        # branch wins a full accept, its head evaluation is the next round's
-        # proposal call
-        heads = jax.vmap(
-            lambda yp: jax.lax.dynamic_index_in_dim(
-                yp, theta_live - 1, axis=0, keepdims=False)
-        )(plan.y_props_b)  # (B, *event)
-        pts = jnp.concatenate([y_prev_f, heads], axis=0)
-        ts = jnp.concatenate(
-            [ts_f, jnp.broadcast_to(plan.t_w1[theta_live], (B,))], axis=0)
-        g_all = model_fn(ts, pts)
-        g_par = g_all[: B * theta].reshape((B, theta) + ev_shape)
-        g_heads = g_all[B * theta:]
-    else:
-        g_par = model_fn(ts_f, y_prev_f).reshape((B, theta) + ev_shape)
-        g_heads = None
+    with jax.named_scope("asd/verify"):
+        if eager_head:
+            # one head point PER BRANCH at the end of the live window:
+            # whichever branch wins a full accept, its head evaluation is the
+            # next round's proposal call
+            heads = jax.vmap(
+                lambda yp: jax.lax.dynamic_index_in_dim(
+                    yp, theta_live - 1, axis=0, keepdims=False)
+            )(plan.y_props_b)  # (B, *event)
+            pts = jnp.concatenate([y_prev_f, heads], axis=0)
+            ts = jnp.concatenate(
+                [ts_f, jnp.broadcast_to(plan.t_w1[theta_live], (B,))],
+                axis=0)
+            g_all = model_fn(ts, pts)
+            g_par = g_all[: B * theta].reshape((B, theta) + ev_shape)
+            g_heads = g_all[B * theta:]
+        else:
+            g_par = model_fn(ts_f, y_prev_f).reshape((B, theta) + ev_shape)
+            g_heads = None
 
-    m_tgt = (
-        bcast_right(plan.A_w, ev_ndim + 1) * plan.y_prev_b
-        + bcast_right(plan.B_w, ev_ndim + 1) * g_par
-    )
-    sig_bt = jnp.broadcast_to(plan.sig_w, (B, theta))
-    if grs_impl == "kernel":
-        from repro.kernels.grs.ops import grs as grs_k
+    with jax.named_scope("asd/grs"):
+        m_tgt = (
+            bcast_right(plan.A_w, ev_ndim + 1) * plan.y_prev_b
+            + bcast_right(plan.B_w, ev_ndim + 1) * g_par
+        )
+        sig_bt = jnp.broadcast_to(plan.sig_w, (B, theta))
+        if grs_impl == "kernel":
+            from repro.kernels.grs.ops import grs as grs_k
 
-        z_b, acc_b = grs_k(plan.u_w_b, plan.xi_w_b, plan.m_hats_b, m_tgt,
-                           sig_bt, event_ndim=ev_ndim)
-    else:
-        z_b, acc_b = grs(plan.u_w_b, plan.xi_w_b, plan.m_hats_b, m_tgt,
-                         sig_bt, event_ndim=ev_ndim)
+            z_b, acc_b = grs_k(plan.u_w_b, plan.xi_w_b, plan.m_hats_b, m_tgt,
+                               sig_bt, event_ndim=ev_ndim)
+        else:
+            z_b, acc_b = grs(plan.u_w_b, plan.xi_w_b, plan.m_hats_b, m_tgt,
+                             sig_bt, event_ndim=ev_ndim)
 
     slot = jnp.arange(theta)
     acc_m = acc_b & (slot[None, :] < plan.n_valid)  # (B, theta)

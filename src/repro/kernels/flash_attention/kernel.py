@@ -123,4 +123,5 @@ def flash_attention(
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

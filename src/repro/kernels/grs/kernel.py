@@ -88,5 +88,6 @@ def grs_pallas(u, sigma, xi, m_hat, m, interpret: bool = False):
             jax.ShapeDtypeStruct((R, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="grs",
     )(u[:, None], sigma[:, None], xi, m_hat, m)
     return z, acc[:, 0]

@@ -49,8 +49,10 @@ def _copy_kernel(idx_ref, *refs):
         dst[...] = src[...]
 
 
-def gather_tables(tables, idx, interpret: bool = False):
-    """out_k[p] = tables[k][idx[p]] for every (N, D_k) table in ONE kernel.
+def gather_tables(tables, idx, interpret: bool = False,
+                  name: str = "gather_rows"):
+    """out_k[p] = tables[k][idx[p]] for every (N, D_k) table in ONE kernel
+    named ``name``.
 
     D_k % 128 == 0; idx: (M,) int32 in [0, N).  Returns [(M, D_k), ...]."""
     tiles = [as_row_tiles(t) for t in tables]
@@ -66,6 +68,7 @@ def gather_tables(tables, idx, interpret: bool = False):
         out_shape=[jax.ShapeDtypeStruct((M,) + t.shape[1:], t.dtype)
                    for t in tiles],
         interpret=interpret,
+        name=name,
     )(idx, *tiles)
     return [o.reshape(M, -1) for o in outs]
 
@@ -109,5 +112,6 @@ def scatter_rows_pallas(vals, idx, num_rows: int, interpret: bool = False):
         out_shape=jax.ShapeDtypeStruct(zeros.shape, zeros.dtype),
         input_output_aliases={2: 0},  # operands: (idx, vals, zeros)
         interpret=interpret,
+        name="scatter_rows",
     )(idx, tiles, zeros)
     return out[:num_rows].reshape(num_rows, -1)

@@ -59,4 +59,5 @@ def ssm_scan(a, b, *, block_t: int = BLOCK_T, block_d: int = BLOCK_D,
         out_shape=jax.ShapeDtypeStruct((B, L, D), a.dtype),
         scratch_shapes=[pltpu.VMEM((block_d,), jnp.float32)],
         interpret=interpret,
+        name="ssm_scan",
     )(a, b)

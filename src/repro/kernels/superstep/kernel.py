@@ -50,7 +50,8 @@ def fused_gather_pallas(y, xi, mh, sc, idx, interpret: bool = False):
     D % 128 == 0, C % 128 == 0.  Returns the four packed row sets
     ((M, D) x 3, (M, C)) in one kernel launch.
     """
-    return tuple(gather_tables([y, xi, mh, sc], idx, interpret=interpret))
+    return tuple(gather_tables([y, xi, mh, sc], idx, interpret=interpret,
+                               name="fused_gather"))
 
 
 def _total(x):
@@ -111,5 +112,6 @@ def fused_verify_commit_pallas(u, sigma, A, B, y, g, xi, mh, idx,
         # operands: (idx, scal, y, g, xi, mh, z0, a0)
         input_output_aliases={6: 0, 7: 1},
         interpret=interpret,
+        name="fused_verify_commit",
     )(idx, scal, *rows, z0, a0)
     return z[:num_rows].reshape(num_rows, -1), acc[:num_rows, 0, 0]

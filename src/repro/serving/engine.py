@@ -100,7 +100,8 @@ class ContinuousASDEngine(ShardWorker):
         """
         if not self.scheduler.has_work():
             return False
-        self._harvest(self._dispatch_superstep())
+        with self._span("step", superstep=self.stats.supersteps):
+            self._harvest(self._dispatch_superstep())
         return self.scheduler.has_work()
 
     def serve(self, requests: list[Request], key=None) -> dict[int, np.ndarray]:
@@ -129,17 +130,19 @@ class ContinuousASDEngine(ShardWorker):
             self.submit(r)
         pending = None
         while self.scheduler.has_work() or pending is not None:
-            if pending is not None and self.scheduler.queue_depth > 0:
-                # someone is waiting for a slot: sync the boundary so the
-                # dispatch below can admit into lanes superstep s freed
-                self._harvest(pending)
-                pending = None
-            nxt = None
-            if self.scheduler.has_work():
-                nxt = self._dispatch_superstep()
-            if pending is not None:
-                self._harvest(pending)  # overlaps the dispatch in flight
-            pending = nxt
+            with self._span("step", superstep=self.stats.supersteps):
+                if pending is not None and self.scheduler.queue_depth > 0:
+                    # someone is waiting for a slot: sync the boundary so
+                    # the dispatch below can admit into lanes superstep s
+                    # freed
+                    self._harvest(pending)
+                    pending = None
+                nxt = None
+                if self.scheduler.has_work():
+                    nxt = self._dispatch_superstep()
+                if pending is not None:
+                    self._harvest(pending)  # overlaps the dispatch in flight
+                pending = nxt
         jax.block_until_ready(self._states.a)
         self.stats.wall_time += time.perf_counter() - t0
         self._refresh_health()

@@ -2,9 +2,11 @@
 
 Three parts, all host-side and zero-cost when unused:
 
-  * ``TraceRecorder`` — fixed-capacity ring buffer of request-lifecycle and
-    superstep-boundary spans, exportable as Chrome trace-event JSON
-    (open in https://ui.perfetto.dev).  Engines take ``tracer=None``.
+  * ``span`` / ``TraceRecorder`` — host spans on the profiler's clock
+    (``repro.*`` annotations in a ``jax.profiler`` trace) and a
+    fixed-capacity ring buffer of them plus request-lifecycle spans,
+    exportable as Chrome trace-event JSON (open in
+    https://ui.perfetto.dev).  Engines take ``tracer=None``.
   * ``MetricsRegistry`` / ``instrument_engine`` — Prometheus-style
     counters/gauges/histograms fed by scrape-time callbacks over the
     engines' existing ``EngineStats``/scheduler state.
@@ -21,7 +23,7 @@ from repro.serving.obs.registry import (
     MetricsRegistry,
     instrument_engine,
 )
-from repro.serving.obs.trace import TraceRecorder
+from repro.serving.obs.trace import TraceRecorder, span
 
 __all__ = [
     "Counter",
@@ -32,4 +34,5 @@ __all__ = [
     "PROM_CONTENT_TYPE",
     "TraceRecorder",
     "instrument_engine",
+    "span",
 ]

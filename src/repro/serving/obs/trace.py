@@ -1,22 +1,39 @@
-"""Structured tracing for the serving engines: a fixed-capacity ring buffer
-of spans, exportable as Chrome trace-event JSON.
+"""Structured tracing for the serving engines: host spans on the
+profiler's clock, and a fixed-capacity ring buffer of them, exportable as
+Chrome trace-event JSON.
 
-Design constraints (these ARE the feature):
+One mechanism, ``span(tracer, name, **args)``:
+
+  * It always enters ``jax.profiler.TraceAnnotation("repro." + name)``.
+    Under a profiler (``jax.profiler.trace``) the span lands in the same
+    ``.xplane.pb`` as the device ops, on the same clock, so every idle gap
+    of the device can be put down to what the host was doing in it.  With
+    the profiler off the annotation is one small object and records nothing.
+  * When a ``TraceRecorder`` is attached and enabled, the span also appends
+    the same interval to the ring.
+
+Design constraints of the ring (these ARE the feature):
 
   * O(1) append into a preallocated ring — recording a span is a few tuple
     stores, no allocation growth, no locks (the serve loops are
     single-threaded per engine; the metrics HTTP thread only READS exported
     snapshots).
-  * Zero device-side cost: every span is built from host timestamps the
-    engine already takes for ``EngineStats`` (dispatch walls, the harvest's
-    block_until_ready bracket, scheduler submit/admit stamps).  Tracing
-    never adds a ``block_until_ready`` or a transfer.
-  * Off by default: engines take ``tracer=None`` and guard every record
-    site with one ``is not None`` check, so the tracing-off overhead is a
-    single attribute test per boundary.
+  * Zero device-side cost: spans are host timestamps around work the engine
+    does anyway.  Tracing never adds a ``block_until_ready`` or a transfer.
+  * Off by default: engines take ``tracer=None``; a disabled or absent ring
+    costs no clock read and no ring slot.
   * Overflow drops the OLDEST spans (ring semantics) and counts them in
     ``dropped`` — a long serve with a small buffer keeps the most recent
     window instead of dying or silently truncating the tail.
+
+The clock: the profiler stamps host events with ``CLOCK_REALTIME``
+(``time.time_ns``) and writes them relative to the session's
+``profile_start_time`` (a stat of the trace's ``Task Environment`` plane).
+The ring records on that clock too, so a ring export and a profiler trace
+of the same run line up: a ring time ``t`` sits at
+``t * 1e9 - profile_start_time`` ns in the trace.  Stamps a caller took on
+``time.perf_counter`` (the scheduler's submit/admit times) are moved onto it
+with ``TraceRecorder.from_perf``.
 
 Lane conventions (how the engines use pid/tid):
 
@@ -24,7 +41,8 @@ Lane conventions (how the engines use pid/tid):
     one Perfetto row per slot, "queued" (submit -> admit) and "request"
     (admit -> retire) spans with rid/rounds/accepts/theta_live attributes.
   * boundary spans: ``pid`` = shard id, ``tid`` = num_slots + lane —
-    dispatch / device / harvest / collective rows underneath the slots.
+    dispatch (or compile) / device_wait / harvest / admit / step rows
+    underneath the slots.
   * fused front-end spans: ``pid`` = num_shards (one past the shard ids),
     named "frontend" — the single fused dispatch/device-wait lanes.
 
@@ -38,6 +56,64 @@ from __future__ import annotations
 import json
 import time
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
+
+# every program span's name in a profiler trace starts with this
+SPAN_PREFIX = "repro."
+
+
+def clock() -> float:
+    """Seconds on the clock the profiler stamps host events with
+    (``CLOCK_REALTIME``)."""
+    return time.time_ns() * 1e-9
+
+
+class span:
+    """One host interval of the serving program: a profiler annotation
+    named ``repro.<name>`` with ``args`` as its metadata, and, when
+    ``tracer`` is an enabled ``TraceRecorder``, the same interval in its
+    ring on lane (``pid``, ``tid``).
+
+    ``set(**args)`` adds arguments known only at the span's end.  A
+    profiler reader should cut a raw event name at ``#``: the annotation
+    folds its arguments into the name as ``name#k=v#``.
+    """
+
+    __slots__ = ("_ann", "_ring", "_t0", "name", "args", "pid", "tid",
+                 "pname", "tname")
+
+    def __init__(self, tracer, name: str, *, pid: int = 0, tid: int = 0,
+                 pname: Optional[str] = None, tname: Optional[str] = None,
+                 **args):
+        self._ann = TraceAnnotation(SPAN_PREFIX + name, **args)
+        self._ring = tracer if tracer is not None and tracer.enabled else None
+        if self._ring is not None:
+            self.name, self.args = name, args
+            self.pid, self.tid, self.pname, self.tname = pid, tid, pname, tname
+
+    @property
+    def on(self) -> bool:
+        """Whether anything records this span (the ring or a profiler)."""
+        return self._ring is not None or TraceAnnotation.is_enabled()
+
+    def set(self, **args) -> None:
+        self._ann.set_metadata(**args)
+        if self._ring is not None:
+            self.args.update(args)
+
+    def __enter__(self) -> "span":
+        if self._ring is not None:
+            self._t0 = clock()
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._ann.__exit__(*exc)
+        if self._ring is not None:
+            self._ring.add_span(self.name, self._t0, clock(), pid=self.pid,
+                                tid=self.tid, pname=self.pname,
+                                tname=self.tname, args=self.args)
 
 
 class TraceRecorder:
@@ -57,8 +133,12 @@ class TraceRecorder:
         self.enabled = bool(enabled)
         self.dropped = 0
         # epoch: export timestamps are relative to recorder construction so
-        # traces from one run are comparable and deterministic in layout
-        self.epoch = time.perf_counter()
+        # traces from one run are comparable and deterministic in layout;
+        # the export carries it, to line the ring up with a profiler trace
+        self.epoch_ns = time.time_ns()
+        self.epoch = self.epoch_ns * 1e-9
+        # perf_counter -> profiler clock, for stamps callers already took
+        self._perf_offset = clock() - time.perf_counter()
         self._buf: list = [None] * self.capacity
         self._start = 0  # ring read position
         self._n = 0      # live events
@@ -73,8 +153,12 @@ class TraceRecorder:
 
     @staticmethod
     def now() -> float:
-        """The clock spans are recorded against (``time.perf_counter``)."""
-        return time.perf_counter()
+        """The clock spans are recorded against: the profiler's."""
+        return clock()
+
+    def from_perf(self, t: float) -> float:
+        """A ``time.perf_counter`` stamp on the ring's clock."""
+        return t + self._perf_offset
 
     def _append(self, event: tuple) -> None:
         if self._n == self.capacity:  # drop-oldest ring overflow
@@ -98,7 +182,7 @@ class TraceRecorder:
                  pid: int = 0, tid: int = 0,
                  pname: Optional[str] = None, tname: Optional[str] = None,
                  args: Optional[dict] = None) -> None:
-        """Record one complete span [t0, t1] (perf_counter seconds)."""
+        """Record one complete span [t0, t1] (``now()`` seconds)."""
         if not self.enabled:
             return
         self._register(pid, tid, pname, tname)
@@ -109,7 +193,7 @@ class TraceRecorder:
                     pid: int = 0, tid: int = 0,
                     pname: Optional[str] = None, tname: Optional[str] = None,
                     args: Optional[dict] = None) -> None:
-        """Record one instant event at ``t`` (perf_counter seconds)."""
+        """Record one instant event at ``t`` (``now()`` seconds)."""
         if not self.enabled:
             return
         self._register(pid, tid, pname, tname)
@@ -167,7 +251,9 @@ class TraceRecorder:
                 ev["args"] = {k: v for k, v in args.items() if v is not None}
             events.append(ev)
         return {"traceEvents": events, "displayTimeUnit": "ms",
-                "droppedEvents": self.dropped}
+                "droppedEvents": self.dropped,
+                "otherData": {"clock": "CLOCK_REALTIME",
+                              "epoch_ns": self.epoch_ns}}
 
     def export_chrome_trace(self, path: str) -> dict:
         """Write the Chrome trace JSON to ``path`` (open in Perfetto:

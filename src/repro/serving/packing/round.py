@@ -76,6 +76,17 @@ def _gather_scalar(table: jax.Array, slot_id, step_id) -> jax.Array:
     return table[slot_id, step_id]
 
 
+def _target_grs(A, B, y, g, u, xi, mh, sig, grs_impl: str, ev_ndim: int):
+    """The unfused verify side of a packed round: target mean
+    ``m = A y + B g`` per packed point, then one GRS pass."""
+    m_tgt = bcast_right(A, ev_ndim + 1) * y + bcast_right(B, ev_ndim + 1) * g
+    if grs_impl == "kernel":
+        from repro.kernels.grs.ops import grs as grs_k
+
+        return grs_k(u, xi, mh, m_tgt, sig, event_ndim=ev_ndim)
+    return grs(u, xi, mh, m_tgt, sig, event_ndim=ev_ndim)
+
+
 def packed_round(
     make_fn: Callable,
     params,
@@ -136,116 +147,114 @@ def packed_round(
         plans = jax.vmap(plan_one)(states, conds)
 
     # --- 2. pack: allocate the budget, build maps, gather live points -------
-    active = states.a < K
-    demand = jnp.where(active, plans.n_valid, 0).astype(jnp.int32)
-    # budget-as-data: the allocator splits the (possibly traced) tier, the
-    # maps below are built at the static cap — lanes past the granted total
-    # are padding and drop at the commit scatter
-    grants = allocator.allocate(
-        demand, budget if budget_data is None else budget_data, weights)
-    grants = jnp.minimum(grants, demand)  # contract guard: g <= d always
-    # a fully-granted slot runs its true live window (head index included);
-    # a trimmed slot runs the grant as its effective window this round.  A
-    # zero grant (only possible when budget < #active slots) is a safe stall:
-    # theta_r = 0 verifies nothing, commits nothing, and advances nowhere.
-    theta_r = jnp.where(grants >= demand, plans.theta_live, grants)
-    maps = build_pack_maps(grants, budget)
-    src_rows = jnp.where(  # gather side: padding lanes re-read row 0
-        maps.valid, maps.slot_id * theta + maps.step_id, 0
-    )
-
-    def flat(x):  # (S, theta, *ev) -> (S*theta, *ev)
-        return x.reshape((S * theta,) + x.shape[2:])
-
-    if round_impl == "fused":
-        from repro.kernels.superstep import fused_gather
-
-        # the five per-point scalars ride as lanes of ONE (S*theta, 5)
-        # table, so the fused gather moves event rows and scalars together
-        scal_tbl = jnp.stack(
-            [flat(plans.t_w1[:, :theta]), flat(plans.u_w),
-             flat(plans.A_w), flat(plans.B_w), flat(plans.sig_w)], axis=-1)
-        y_pt, xi_pt, mh_pt, scal_pt = fused_gather(
-            flat(plans.y_prev), flat(plans.xi_w), flat(plans.m_hats),
-            scal_tbl, src_rows, impl=pack_impl)
-        t_pt, u_pt, A_pt, B_pt, sig_pt = (
-            scal_pt[:, i] for i in range(5))
-    else:
-        y_pt = gather_rows(flat(plans.y_prev), src_rows, impl=pack_impl)
-        xi_pt = gather_rows(flat(plans.xi_w), src_rows, impl=pack_impl)
-        mh_pt = gather_rows(flat(plans.m_hats), src_rows, impl=pack_impl)
-        t_pt = _gather_scalar(plans.t_w1[:, :theta], maps.slot_id,
-                              maps.step_id)
-        u_pt = _gather_scalar(plans.u_w, maps.slot_id, maps.step_id)
-        A_pt = _gather_scalar(plans.A_w, maps.slot_id, maps.step_id)
-        B_pt = _gather_scalar(plans.B_w, maps.slot_id, maps.step_id)
-        sig_pt = _gather_scalar(plans.sig_w, maps.slot_id, maps.step_id)
-
-    if eager_head:
-        # one fixed head lane per slot: the point the chain lands on when it
-        # accepts its whole effective window — next round's proposal call
-        y_head = jax.vmap(
-            lambda yp, tr: jax.lax.dynamic_index_in_dim(
-                yp, tr - 1, axis=0, keepdims=False)
-        )(plans.y_props, theta_r)
-        t_head = jax.vmap(lambda tw, tr: tw[tr])(plans.t_w1, theta_r)
-        ts_all = jnp.concatenate([t_pt, t_head], axis=0)
-        ys_all = jnp.concatenate([y_pt, y_head], axis=0)
-        conds_all = (
-            None if conds is None
-            else jnp.concatenate([conds[maps.slot_id], conds], axis=0)
+    with jax.named_scope("asd/pack"):
+        active = states.a < K
+        demand = jnp.where(active, plans.n_valid, 0).astype(jnp.int32)
+        # budget-as-data: the allocator splits the (possibly traced) tier,
+        # the maps below are built at the static cap — lanes past the granted
+        # total are padding and drop at the commit scatter
+        grants = allocator.allocate(
+            demand, budget if budget_data is None else budget_data, weights)
+        grants = jnp.minimum(grants, demand)  # contract guard: g <= d always
+        # a fully-granted slot runs its true live window (head index
+        # included); a trimmed slot runs the grant as its effective window
+        # this round.  A zero grant (only possible when budget < #active
+        # slots) is a safe stall: theta_r = 0 verifies nothing, commits
+        # nothing, and advances nowhere.
+        theta_r = jnp.where(grants >= demand, plans.theta_live, grants)
+        maps = build_pack_maps(grants, budget)
+        src_rows = jnp.where(  # gather side: padding lanes re-read row 0
+            maps.valid, maps.slot_id * theta + maps.step_id, 0
         )
-    else:
-        ts_all, ys_all = t_pt, y_pt
-        conds_all = None if conds is None else conds[maps.slot_id]
+
+        def flat(x):  # (S, theta, *ev) -> (S*theta, *ev)
+            return x.reshape((S * theta,) + x.shape[2:])
+
+        if round_impl == "fused":
+            from repro.kernels.superstep import fused_gather
+
+            # the five per-point scalars ride as lanes of ONE (S*theta, 5)
+            # table, so the fused gather moves event rows and scalars together
+            scal_tbl = jnp.stack(
+                [flat(plans.t_w1[:, :theta]), flat(plans.u_w),
+                 flat(plans.A_w), flat(plans.B_w), flat(plans.sig_w)], axis=-1)
+            y_pt, xi_pt, mh_pt, scal_pt = fused_gather(
+                flat(plans.y_prev), flat(plans.xi_w), flat(plans.m_hats),
+                scal_tbl, src_rows, impl=pack_impl)
+            t_pt, u_pt, A_pt, B_pt, sig_pt = (
+                scal_pt[:, i] for i in range(5))
+        else:
+            y_pt = gather_rows(flat(plans.y_prev), src_rows, impl=pack_impl)
+            xi_pt = gather_rows(flat(plans.xi_w), src_rows, impl=pack_impl)
+            mh_pt = gather_rows(flat(plans.m_hats), src_rows, impl=pack_impl)
+            t_pt = _gather_scalar(plans.t_w1[:, :theta], maps.slot_id,
+                                  maps.step_id)
+            u_pt = _gather_scalar(plans.u_w, maps.slot_id, maps.step_id)
+            A_pt = _gather_scalar(plans.A_w, maps.slot_id, maps.step_id)
+            B_pt = _gather_scalar(plans.B_w, maps.slot_id, maps.step_id)
+            sig_pt = _gather_scalar(plans.sig_w, maps.slot_id, maps.step_id)
+
+        if eager_head:
+            # one fixed head lane per slot: the point the chain lands on when
+            # it accepts its whole effective window — next round's proposal
+            # call
+            y_head = jax.vmap(
+                lambda yp, tr: jax.lax.dynamic_index_in_dim(
+                    yp, tr - 1, axis=0, keepdims=False)
+            )(plans.y_props, theta_r)
+            t_head = jax.vmap(lambda tw, tr: tw[tr])(plans.t_w1, theta_r)
+            ts_all = jnp.concatenate([t_pt, t_head], axis=0)
+            ys_all = jnp.concatenate([y_pt, y_head], axis=0)
+            conds_all = (
+                None if conds is None
+                else jnp.concatenate([conds[maps.slot_id], conds], axis=0)
+            )
+        else:
+            ts_all, ys_all = t_pt, y_pt
+            conds_all = None if conds is None else conds[maps.slot_id]
 
     # --- 3. verify: ONE budget-shaped model call + ONE GRS pass -------------
-    if conds is None:
-        g_all = make_fn(params, None)(ts_all, ys_all)
-    else:
-        g_all = jax.vmap(
-            lambda t, y, c: make_fn(params, c)(t[None], y[None])[0]
-        )(ts_all, ys_all, conds_all)
-    if eager_head:
-        g_pt, g_head = g_all[:budget], g_all[budget:]
-    else:
-        g_pt, g_head = g_all, None
+    with jax.named_scope("asd/verify"):
+        if conds is None:
+            g_all = make_fn(params, None)(ts_all, ys_all)
+        else:
+            g_all = jax.vmap(
+                lambda t, y, c: make_fn(params, c)(t[None], y[None])[0]
+            )(ts_all, ys_all, conds_all)
+        if eager_head:
+            g_pt, g_head = g_all[:budget], g_all[budget:]
+        else:
+            g_pt, g_head = g_all, None
 
     drop_rows = maps.row_id(theta)  # padding lanes -> the drop row
     if round_impl == "fused":
         from repro.kernels.superstep import fused_verify_commit
 
         # target mean + GRS + both commit scatters in ONE program
-        z_tbl, acc_tbl = fused_verify_commit(
-            y_pt, g_pt, xi_pt, mh_pt, A_pt, B_pt, u_pt, sig_pt,
-            drop_rows, S * theta, impl=pack_impl)
+        with jax.named_scope("asd/grs"):
+            z_tbl, acc_tbl = fused_verify_commit(
+                y_pt, g_pt, xi_pt, mh_pt, A_pt, B_pt, u_pt, sig_pt,
+                drop_rows, S * theta, impl=pack_impl)
         z_seg = z_tbl.reshape((S, theta) + z_tbl.shape[1:])
         acc_seg = acc_tbl.reshape(S, theta)
     else:
-        m_tgt_pt = (
-            bcast_right(A_pt, ev_ndim + 1) * y_pt
-            + bcast_right(B_pt, ev_ndim + 1) * g_pt
-        )
-        if grs_impl == "kernel":
-            from repro.kernels.grs.ops import grs as grs_k
-
-            z_pt, acc_pt = grs_k(u_pt, xi_pt, mh_pt, m_tgt_pt, sig_pt,
-                                 event_ndim=ev_ndim)
-        else:
-            z_pt, acc_pt = grs(u_pt, xi_pt, mh_pt, m_tgt_pt, sig_pt,
-                               event_ndim=ev_ndim)
+        with jax.named_scope("asd/grs"):
+            z_pt, acc_pt = _target_grs(
+                A_pt, B_pt, y_pt, g_pt, u_pt, xi_pt, mh_pt, sig_pt,
+                grs_impl, ev_ndim)
 
         # --- 4. commit: scatter back and close each slot's round ------------
         from repro.kernels.pack import scatter_rows
 
-        z_seg = scatter_rows(
-            z_pt, drop_rows, S * theta, impl=pack_impl
-        ).reshape((S, theta) + z_pt.shape[1:])
-        acc_seg = (
-            jnp.zeros((S * theta + 1,), bool)
-            .at[drop_rows].set(acc_pt)[: S * theta]
-            .reshape(S, theta)
-        )
+        with jax.named_scope("asd/commit"):
+            z_seg = scatter_rows(
+                z_pt, drop_rows, S * theta, impl=pack_impl
+            ).reshape((S, theta) + z_pt.shape[1:])
+            acc_seg = (
+                jnp.zeros((S * theta + 1,), bool)
+                .at[drop_rows].set(acc_pt)[: S * theta]
+                .reshape(S, theta)
+            )
 
     def commit_one(st, plan, z, acc, gh, tr):
         return commit_round(
@@ -315,124 +324,122 @@ def _branched_packed_round(
         plans = jax.vmap(plan_one)(states, conds)
 
     # --- 2. pack: branched demand, branch-shedding grant split, gather ------
-    active = states.a < K
-    n1 = plans.n_valid.astype(jnp.int32)  # live points PER BRANCH
-    b_live = jnp.clip(states.b_live, 1, NB)
-    demand = jnp.where(active, b_live * n1, 0).astype(jnp.int32)
-    grants = allocator.allocate(
-        demand, budget if budget_data is None else budget_data, weights)
-    grants = jnp.minimum(grants, demand)
-    covered = grants >= n1
-    # branches granted: whole windows only (a partial extra branch cannot
-    # beat branch 0's full prefix, so its points would be pure waste)
-    b_r = jnp.clip(grants // jnp.maximum(n1, 1), 1, b_live)
-    theta_r = jnp.where(covered, plans.theta_live, grants)
-    pts1 = jnp.where(covered, n1, grants)  # == min(theta_r, K - a)
-    maps = build_branched_pack_maps(pts1, b_r, budget)
-    src_rows = jnp.where(
-        maps.valid,
-        (maps.slot_id * NB + maps.branch_id) * theta + maps.step_id, 0)
+    with jax.named_scope("asd/pack"):
+        active = states.a < K
+        n1 = plans.n_valid.astype(jnp.int32)  # live points PER BRANCH
+        b_live = jnp.clip(states.b_live, 1, NB)
+        demand = jnp.where(active, b_live * n1, 0).astype(jnp.int32)
+        grants = allocator.allocate(
+            demand, budget if budget_data is None else budget_data, weights)
+        grants = jnp.minimum(grants, demand)
+        covered = grants >= n1
+        # branches granted: whole windows only (a partial extra branch cannot
+        # beat branch 0's full prefix, so its points would be pure waste)
+        b_r = jnp.clip(grants // jnp.maximum(n1, 1), 1, b_live)
+        theta_r = jnp.where(covered, plans.theta_live, grants)
+        pts1 = jnp.where(covered, n1, grants)  # == min(theta_r, K - a)
+        maps = build_branched_pack_maps(pts1, b_r, budget)
+        src_rows = jnp.where(
+            maps.valid,
+            (maps.slot_id * NB + maps.branch_id) * theta + maps.step_id, 0)
 
-    def flatb(x):  # (S, B, theta, *ev) -> (S*B*theta, *ev)
-        return x.reshape((S * NB * theta,) + x.shape[3:])
+        def flatb(x):  # (S, B, theta, *ev) -> (S*B*theta, *ev)
+            return x.reshape((S * NB * theta,) + x.shape[3:])
 
-    def btile(x):  # per-slot (S, theta) scalar window -> (S, B, theta)
-        return jnp.broadcast_to(x[:, None, :], (S, NB, theta))
+        def btile(x):  # per-slot (S, theta) scalar window -> (S, B, theta)
+            return jnp.broadcast_to(x[:, None, :], (S, NB, theta))
 
-    t_tbl = btile(plans.t_w1[:, :theta])
-    A_tbl = btile(plans.A_w)
-    B_tbl = btile(plans.B_w)
-    sig_tbl = btile(plans.sig_w)
+        t_tbl = btile(plans.t_w1[:, :theta])
+        A_tbl = btile(plans.A_w)
+        B_tbl = btile(plans.B_w)
+        sig_tbl = btile(plans.sig_w)
 
-    if round_impl == "fused":
-        from repro.kernels.superstep import fused_gather
+        if round_impl == "fused":
+            from repro.kernels.superstep import fused_gather
 
-        scal_tbl = jnp.stack(
-            [flatb(t_tbl), flatb(plans.u_w_b), flatb(A_tbl), flatb(B_tbl),
-             flatb(sig_tbl)], axis=-1)
-        y_pt, xi_pt, mh_pt, scal_pt = fused_gather(
-            flatb(plans.y_prev_b), flatb(plans.xi_w_b),
-            flatb(plans.m_hats_b), scal_tbl, src_rows, impl=pack_impl)
-        t_pt, u_pt, A_pt, B_pt, sig_pt = (
-            scal_pt[:, i] for i in range(5))
-    else:
-        y_pt = gather_rows(flatb(plans.y_prev_b), src_rows, impl=pack_impl)
-        xi_pt = gather_rows(flatb(plans.xi_w_b), src_rows, impl=pack_impl)
-        mh_pt = gather_rows(flatb(plans.m_hats_b), src_rows, impl=pack_impl)
-        t_pt = _gather_scalar(plans.t_w1[:, :theta], maps.slot_id,
-                              maps.step_id)
-        u_pt = plans.u_w_b[maps.slot_id, maps.branch_id, maps.step_id]
-        A_pt = _gather_scalar(plans.A_w, maps.slot_id, maps.step_id)
-        B_pt = _gather_scalar(plans.B_w, maps.slot_id, maps.step_id)
-        sig_pt = _gather_scalar(plans.sig_w, maps.slot_id, maps.step_id)
+            scal_tbl = jnp.stack(
+                [flatb(t_tbl), flatb(plans.u_w_b), flatb(A_tbl), flatb(B_tbl),
+                 flatb(sig_tbl)], axis=-1)
+            y_pt, xi_pt, mh_pt, scal_pt = fused_gather(
+                flatb(plans.y_prev_b), flatb(plans.xi_w_b),
+                flatb(plans.m_hats_b), scal_tbl, src_rows, impl=pack_impl)
+            t_pt, u_pt, A_pt, B_pt, sig_pt = (
+                scal_pt[:, i] for i in range(5))
+        else:
+            y_pt = gather_rows(flatb(plans.y_prev_b), src_rows, impl=pack_impl)
+            xi_pt = gather_rows(flatb(plans.xi_w_b), src_rows, impl=pack_impl)
+            mh_pt = gather_rows(flatb(plans.m_hats_b), src_rows,
+                                impl=pack_impl)
+            t_pt = _gather_scalar(plans.t_w1[:, :theta], maps.slot_id,
+                                  maps.step_id)
+            u_pt = plans.u_w_b[maps.slot_id, maps.branch_id, maps.step_id]
+            A_pt = _gather_scalar(plans.A_w, maps.slot_id, maps.step_id)
+            B_pt = _gather_scalar(plans.B_w, maps.slot_id, maps.step_id)
+            sig_pt = _gather_scalar(plans.sig_w, maps.slot_id, maps.step_id)
 
-    if eager_head:
-        # one head lane per (slot, branch): whichever branch wins a full
-        # accept, its head evaluation is the next round's proposal call
-        y_head = jax.vmap(
-            lambda yp, tr: jax.vmap(
-                lambda ypb: jax.lax.dynamic_index_in_dim(
-                    ypb, tr - 1, axis=0, keepdims=False))(yp)
-        )(plans.y_props_b, theta_r)  # (S, B, *event)
-        t_head = jax.vmap(lambda tw, tr: tw[tr])(plans.t_w1, theta_r)
-        ts_all = jnp.concatenate([t_pt, jnp.repeat(t_head, NB)], axis=0)
-        ys_all = jnp.concatenate(
-            [y_pt, y_head.reshape((S * NB,) + ev_shape)], axis=0)
-        conds_all = (
-            None if conds is None
-            else jnp.concatenate(
-                [conds[maps.slot_id], jnp.repeat(conds, NB, axis=0)], axis=0)
-        )
-    else:
-        ts_all, ys_all = t_pt, y_pt
-        conds_all = None if conds is None else conds[maps.slot_id]
+        if eager_head:
+            # one head lane per (slot, branch): whichever branch wins a full
+            # accept, its head evaluation is the next round's proposal call
+            y_head = jax.vmap(
+                lambda yp, tr: jax.vmap(
+                    lambda ypb: jax.lax.dynamic_index_in_dim(
+                        ypb, tr - 1, axis=0, keepdims=False))(yp)
+            )(plans.y_props_b, theta_r)  # (S, B, *event)
+            t_head = jax.vmap(lambda tw, tr: tw[tr])(plans.t_w1, theta_r)
+            ts_all = jnp.concatenate([t_pt, jnp.repeat(t_head, NB)], axis=0)
+            ys_all = jnp.concatenate(
+                [y_pt, y_head.reshape((S * NB,) + ev_shape)], axis=0)
+            conds_all = (
+                None if conds is None
+                else jnp.concatenate(
+                    [conds[maps.slot_id], jnp.repeat(conds, NB, axis=0)],
+                    axis=0)
+            )
+        else:
+            ts_all, ys_all = t_pt, y_pt
+            conds_all = None if conds is None else conds[maps.slot_id]
 
     # --- 3. verify: ONE budget-shaped model call + ONE GRS pass -------------
-    if conds is None:
-        g_all = make_fn(params, None)(ts_all, ys_all)
-    else:
-        g_all = jax.vmap(
-            lambda t, y, c: make_fn(params, c)(t[None], y[None])[0]
-        )(ts_all, ys_all, conds_all)
-    if eager_head:
-        g_pt = g_all[:budget]
-        g_head = g_all[budget:].reshape((S, NB) + ev_shape)
-    else:
-        g_pt, g_head = g_all, None
+    with jax.named_scope("asd/verify"):
+        if conds is None:
+            g_all = make_fn(params, None)(ts_all, ys_all)
+        else:
+            g_all = jax.vmap(
+                lambda t, y, c: make_fn(params, c)(t[None], y[None])[0]
+            )(ts_all, ys_all, conds_all)
+        if eager_head:
+            g_pt = g_all[:budget]
+            g_head = g_all[budget:].reshape((S, NB) + ev_shape)
+        else:
+            g_pt, g_head = g_all, None
 
     drop_rows = maps.row_id(NB, theta)
     if round_impl == "fused":
         from repro.kernels.superstep import fused_verify_commit
 
-        z_tbl, acc_tbl = fused_verify_commit(
-            y_pt, g_pt, xi_pt, mh_pt, A_pt, B_pt, u_pt, sig_pt,
-            drop_rows, S * NB * theta, impl=pack_impl)
+        with jax.named_scope("asd/grs"):
+            z_tbl, acc_tbl = fused_verify_commit(
+                y_pt, g_pt, xi_pt, mh_pt, A_pt, B_pt, u_pt, sig_pt,
+                drop_rows, S * NB * theta, impl=pack_impl)
         z_seg = z_tbl.reshape((S, NB, theta) + z_tbl.shape[1:])
         acc_seg = acc_tbl.reshape(S, NB, theta)
     else:
-        m_tgt_pt = (
-            bcast_right(A_pt, ev_ndim + 1) * y_pt
-            + bcast_right(B_pt, ev_ndim + 1) * g_pt
-        )
-        if grs_impl == "kernel":
-            from repro.kernels.grs.ops import grs as grs_k
-
-            z_pt, acc_pt = grs_k(u_pt, xi_pt, mh_pt, m_tgt_pt, sig_pt,
-                                 event_ndim=ev_ndim)
-        else:
-            z_pt, acc_pt = grs(u_pt, xi_pt, mh_pt, m_tgt_pt, sig_pt,
-                               event_ndim=ev_ndim)
+        with jax.named_scope("asd/grs"):
+            z_pt, acc_pt = _target_grs(
+                A_pt, B_pt, y_pt, g_pt, u_pt, xi_pt, mh_pt, sig_pt,
+                grs_impl, ev_ndim)
 
         from repro.kernels.pack import scatter_rows
 
-        z_seg = scatter_rows(
-            z_pt, drop_rows, S * NB * theta, impl=pack_impl
-        ).reshape((S, NB, theta) + z_pt.shape[1:])
-        acc_seg = (
-            jnp.zeros((S * NB * theta + 1,), bool)
-            .at[drop_rows].set(acc_pt)[: S * NB * theta]
-            .reshape(S, NB, theta)
-        )
+        with jax.named_scope("asd/commit"):
+            z_seg = scatter_rows(
+                z_pt, drop_rows, S * NB * theta, impl=pack_impl
+            ).reshape((S, NB, theta) + z_pt.shape[1:])
+            acc_seg = (
+                jnp.zeros((S * NB * theta + 1,), bool)
+                .at[drop_rows].set(acc_pt)[: S * NB * theta]
+                .reshape(S, NB, theta)
+            )
 
     # --- 4. select the longest accepted prefix per slot, then commit --------
     slot_idx = jnp.arange(theta)

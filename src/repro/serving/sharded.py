@@ -56,6 +56,7 @@ per-(R, budget) cache.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import time
 from typing import List, Optional
@@ -67,10 +68,18 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.serving.metrics import EngineStats
+from repro.serving.obs.trace import span
 from repro.serving.router import LeastLoaded, Router
 from repro.serving.worker import _SYNC_ROWS, Request, ShardWorker
 
 __all__ = ["ShardedASDEngine"]
+
+# ring lanes of the fused front end's spans (pid = num_shards); "route"
+# instants take lane 2; a compile takes the lane of the dispatch it stands
+# in for
+_FRONTEND_LANES = {"fused_dispatch": 0, "compile": 0,
+                   "fused_device_wait": 1, "harvest": 1, "admit": 3,
+                   "step": 4}
 
 
 class ShardedASDEngine:
@@ -338,6 +347,7 @@ class ShardedASDEngine:
         num_branches = w0.num_branches
         branch_controller = w0.branch_controller
 
+        @jax.named_scope("asd/admit")
         def _admit(states, y0s, keys, flat_idxs):
             # one boundary's admissions for ALL shards: flatten the shard
             # axis, scatter, restore — states donated, sharding re-pinned
@@ -359,6 +369,8 @@ class ShardedASDEngine:
             _admit, donate_argnums=(0,) if w0._donate else (),
             out_shardings=jax.tree_util.tree_map(
                 lambda _: self._sharding, self._states))
+        # padded admission widths ``_fused_admit`` has compiled
+        self._fused_admit_widths: set = set()
 
     def _refresh_weights(self) -> None:
         """Restack the per-shard allocator weights when any worker changed
@@ -398,9 +410,10 @@ class ShardedASDEngine:
             c1 = None if cond is None else cond[0]
             out = w0._run_rounds(
                 st1, c1, p, w[0], R, budget if b is None else b[0])
-            info = jnp.stack(
-                [getattr(out, f).astype(jnp.int32) for f in _SYNC_ROWS])
-            samples = jax.vmap(lambda s: chain_sample(s, K, keep))(out)
+            with jax.named_scope("asd/sync"):
+                info = jnp.stack(
+                    [getattr(out, f).astype(jnp.int32) for f in _SYNC_ROWS])
+                samples = jax.vmap(lambda s: chain_sample(s, K, keep))(out)
             add = jax.tree_util.tree_map(lambda x: x[None], out)
             return add, info[None], samples[None]
 
@@ -449,30 +462,45 @@ class ShardedASDEngine:
         fn = self._fused_fns[key] = jax.jit(fused, donate_argnums=donate)
         return fn
 
+    def _span(self, name: str, **args) -> span:
+        """A boundary span of the fused front end, on its lane of the ring."""
+        return span(self._tracer, name, pid=self.num_shards,
+                    tid=_FRONTEND_LANES[name], pname="frontend",
+                    tname=name, **args)
+
     def _dispatch_fused(self):
         """One boundary for every shard: run each worker's admission policy,
         scatter ALL placed chains in one fused dispatch, then launch ONE
         shard_map superstep covering every shard."""
-        now = time.perf_counter()
-        idxs, y0s, keys = [], [], []
-        S_local = self.workers[0].num_slots
-        conds_touched = False
-        for i, w in enumerate(self.workers):
-            for slot, y0, key, cond_row in w._collect_admissions(now):
-                idxs.append(i * S_local + slot)
-                y0s.append(y0)
-                keys.append(key)
-                if cond_row is not None:
-                    self._conds_host[i, slot] = cond_row
-                    conds_touched = True
-        if idxs:
-            idxs, y0s, keys = ShardWorker._pad_pow2(idxs, y0s, keys)
-            self._states = self._fused_admit(
-                self._states, jnp.stack(y0s), jnp.stack(keys),
-                jnp.asarray(idxs, jnp.int32))
-            if conds_touched:
-                self._conds = jax.device_put(
-                    jnp.asarray(self._conds_host), self._sharding)
+        with self._span("admit") as sp:
+            now = time.perf_counter()
+            idxs, y0s, keys = [], [], []
+            S_local = self.workers[0].num_slots
+            conds_touched = False
+            for i, w in enumerate(self.workers):
+                for slot, y0, key, cond_row in w._collect_admissions(now):
+                    idxs.append(i * S_local + slot)
+                    y0s.append(y0)
+                    keys.append(key)
+                    if cond_row is not None:
+                        self._conds_host[i, slot] = cond_row
+                        conds_touched = True
+            if idxs:
+                admitted = len(idxs)
+                idxs, y0s, keys = ShardWorker._pad_pow2(idxs, y0s, keys)
+                width = len(idxs)
+                if sp.on:
+                    sp.set(admitted=admitted, width=width)
+                cold = width not in self._fused_admit_widths
+                self._fused_admit_widths.add(width)
+                with (self._span("compile", program=f"fused_admit[{width}]")
+                      if cold else contextlib.nullcontext()):
+                    self._states = self._fused_admit(
+                        self._states, jnp.stack(y0s), jnp.stack(keys),
+                        jnp.asarray(idxs, jnp.int32))
+                if conds_touched:
+                    self._conds = jax.device_put(
+                        jnp.asarray(self._conds_host), self._sharding)
         self._refresh_weights()
         # one common R across shards: worker 0 picks, siblings follow
         # (their admission contexts must quantize consistently).  The
@@ -485,19 +513,24 @@ class ShardedASDEngine:
             w._rps = R
         fn = self._get_fused_superstep(R, budget)
         cold = getattr(fn, "_cache_size", lambda: 1)() == 0
-        t0 = time.perf_counter()
-        if self.workers[0]._budget_as_data:
-            budgets = np.asarray(
-                [budget] + [w._pick_budget() for w in self.workers[1:]],
-                np.int32)
-            self._states, info, samples = fn(
-                self._states, self._conds, self.workers[0]._params,
-                self._weights_stacked, jnp.asarray(budgets))
-        else:
-            self._states, info, samples = fn(
-                self._states, self._conds, self.workers[0]._params,
-                self._weights_stacked)
-        t1 = time.perf_counter()
+        label = (self._span("compile", program=f"fused_superstep[R={R}]")
+                 if cold else self._span(
+                     "fused_dispatch", R=R,
+                     budget=budget if budget is not None else 0))
+        with label:
+            t0 = time.perf_counter()
+            if self.workers[0]._budget_as_data:
+                budgets = np.asarray(
+                    [budget] + [w._pick_budget() for w in self.workers[1:]],
+                    np.int32)
+                self._states, info, samples = fn(
+                    self._states, self._conds, self.workers[0]._params,
+                    self._weights_stacked, jnp.asarray(budgets))
+            else:
+                self._states, info, samples = fn(
+                    self._states, self._conds, self.workers[0]._params,
+                    self._weights_stacked)
+            t1 = time.perf_counter()
         if not cold:
             # ONE front-end launch covers every shard: account it on the
             # engine's own fused-dispatch lane.  Splitting it across the
@@ -505,13 +538,6 @@ class ShardedASDEngine:
             # launch time no worker ever spent and skewed every per-shard
             # timing_breakdown().
             self._fused_dispatch_s += t1 - t0
-        tr = self._tracer
-        if tr is not None and tr.enabled:
-            tr.add_span(
-                "fused_dispatch", t0, t1, pid=self.num_shards, tid=0,
-                pname="frontend", tname="dispatch",
-                args={"R": R, "cold": cold,
-                      "budget": budget if budget is not None else 0})
         snapshots = []
         for w in self.workers:
             w.stats.rounds_total += R
@@ -524,18 +550,14 @@ class ShardedASDEngine:
         ordinary harvest on its shard's slice (numpy views pass straight
         through the worker's device_get calls)."""
         (info, samples), snapshots, R, t0, cold = pending
-        t_wait = time.perf_counter()
-        jax.block_until_ready(info)
-        done_at = time.perf_counter()
+        with self._span("fused_device_wait", R=R, cold=cold):
+            t_wait = time.perf_counter()
+            jax.block_until_ready(info)
+            done_at = time.perf_counter()
         wait = done_at - t_wait
-        tr = self._tracer
-        if tr is not None and tr.enabled:
-            tr.add_span(
-                "fused_device_wait", t_wait, done_at, pid=self.num_shards,
-                tid=1, pname="frontend", tname="device",
-                args={"R": R, "cold": cold})
-        info_np = np.asarray(jax.device_get(info))
-        samples_np = np.asarray(jax.device_get(samples))
+        with self._span("harvest"):
+            info_np = np.asarray(jax.device_get(info))
+            samples_np = np.asarray(jax.device_get(samples))
         for i, w in enumerate(self.workers):
             # one completion stamp for the whole boundary: worker i's
             # seconds-per-round EWMA must not absorb workers 0..i-1's
@@ -627,7 +649,7 @@ class ShardedASDEngine:
         tr = self._tracer
         if tr is not None and tr.enabled:
             tr.add_instant(
-                "route", now, pid=self.num_shards, tid=2,
+                "route", tr.from_perf(now), pid=self.num_shards, tid=2,
                 pname="frontend", tname="router",
                 args={"rid": request.rid, "shard": shard})
         self.workers[shard].scheduler.submit(request, now)
@@ -636,16 +658,20 @@ class ShardedASDEngine:
         """One superstep boundary across every shard with work: dispatch all
         (their device programs overlap), then harvest all synchronously.
         Returns True while any shard still has work — the open-loop drive."""
-        if self.dispatch == "fused":
-            if not self.has_work():
-                return False
-            self._harvest_fused(self._dispatch_fused())
-            return self.has_work()
-        pending = [(w, w._dispatch_superstep())
-                   for w in self.workers if w.has_work()]
-        for w, rec in pending:
-            w._harvest(rec)
+        if not self.has_work():
+            return False
+        with self._span("step", superstep=self._supersteps()):
+            if self.dispatch == "fused":
+                self._harvest_fused(self._dispatch_fused())
+            else:
+                pending = [(w, w._dispatch_superstep())
+                           for w in self.workers if w.has_work()]
+                for w, rec in pending:
+                    w._harvest(rec)
         return self.has_work()
+
+    def _supersteps(self) -> int:
+        return max(w.stats.supersteps for w in self.workers)
 
     def serve(self, requests: List[Request], key=None) -> dict:
         """Submit everything through the router, drive all shards until
@@ -678,28 +704,31 @@ class ShardedASDEngine:
             # the boundary, double-buffered exactly like the single engine
             fpending = None
             while self.has_work() or fpending is not None:
-                if fpending is not None and any(
-                        w.scheduler.queue_depth > 0 for w in self.workers):
-                    self._harvest_fused(fpending)
-                    fpending = None
-                nxt = self._dispatch_fused() if self.has_work() else None
-                if fpending is not None:
-                    self._harvest_fused(fpending)
-                fpending = nxt
+                with self._span("step", superstep=self._supersteps()):
+                    if fpending is not None and any(
+                            w.scheduler.queue_depth > 0
+                            for w in self.workers):
+                        self._harvest_fused(fpending)
+                        fpending = None
+                    nxt = self._dispatch_fused() if self.has_work() else None
+                    if fpending is not None:
+                        self._harvest_fused(fpending)
+                    fpending = nxt
             jax.block_until_ready(self._states.a)
         else:
             pending: dict[int, tuple] = {}
             while self.has_work() or pending:
-                for i, w in enumerate(self.workers):
-                    if i in pending and w.scheduler.queue_depth > 0:
-                        w._harvest(pending.pop(i))
-                nxt = {}
-                for i, w in enumerate(self.workers):
-                    if w.has_work():
-                        nxt[i] = w._dispatch_superstep()
-                for i in sorted(pending):
-                    self.workers[i]._harvest(pending.pop(i))
-                pending = nxt
+                with self._span("step", superstep=self._supersteps()):
+                    for i, w in enumerate(self.workers):
+                        if i in pending and w.scheduler.queue_depth > 0:
+                            w._harvest(pending.pop(i))
+                    nxt = {}
+                    for i, w in enumerate(self.workers):
+                        if w.has_work():
+                            nxt[i] = w._dispatch_superstep()
+                    for i in sorted(pending):
+                        self.workers[i]._harvest(pending.pop(i))
+                    pending = nxt
             for w in self.workers:
                 jax.block_until_ready(w._states.a)
         self._wall_time += time.perf_counter() - t0
@@ -748,4 +777,5 @@ class ShardedASDEngine:
                 warm_mp == self.model_shards):
             self._fused_fns = warm._fused_fns
             self._fused_admit = warm._fused_admit
+            self._fused_admit_widths = warm._fused_admit_widths
         return self

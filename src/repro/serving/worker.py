@@ -38,6 +38,7 @@ scalar), collapsing the cache to one executable per R.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import logging
 import time
@@ -65,6 +66,7 @@ from repro.core.controller import (
 from repro.core.schedules import Schedule
 from repro.core.sequential import init_y0
 from repro.serving.metrics import EngineStats, RequestMetrics
+from repro.serving.obs.trace import span
 from repro.serving.scheduler import (
     AdmissionContext,
     SchedulingPolicy,
@@ -77,6 +79,11 @@ from repro.serving.scheduler import (
 # per boundary
 _SYNC_ROWS = ("a", "theta_live", "rounds", "head_calls", "model_evals",
               "accepts", "proposals", "b_live", "draft_points")
+
+# ring lanes of the boundary spans, past the slot rows (tid = slots + lane);
+# a compile takes the lane of the dispatch it stands in for
+_LANES = {"dispatch": 0, "compile": 0, "device_wait": 1, "harvest": 2,
+          "admit": 3, "step": 4}
 
 # the power-of-two ladder auto rounds_per_sync picks from: O(log) compiled
 # superstep variants instead of one per observed value
@@ -193,12 +200,13 @@ class ShardWorker:
         tier drops a rung (upshifts are immediate).
       shard_id: this worker's index in a sharded deployment (0 for the
         single-shard engine); stamped on the worker's ``EngineStats``.
-      tracer: optional ``repro.serving.obs.TraceRecorder``.  When set, the
-        worker records boundary spans (dispatch / device / harvest /
-        collective, one lane each past the slot rows) and request-lifecycle
-        spans (queued + request per slot) against pid = shard_id — all from
-        host timestamps the stats already take, so tracing adds no device
-        syncs.  ``None`` (default): a single attribute test per boundary.
+      tracer: optional ``repro.serving.obs.TraceRecorder``.  Every
+        boundary opens ``repro.*`` profiler spans (``repro.serving.obs.span``:
+        step, admit, dispatch or compile, device_wait, harvest); with a
+        tracer they also land in its ring, one lane each past the slot rows,
+        next to request-lifecycle spans (queued + request per slot), against
+        pid = shard_id.  Tracing adds no device syncs.  ``None`` (default):
+        the profiler spans alone.
       pipelined: deprecated alias kept for compatibility — the serve loops
         are always double-buffered; the flag is ignored.
     """
@@ -441,6 +449,7 @@ class ShardWorker:
             # flags, live windows, counters, and each slot's final sample —
             # so no separate peek dispatch ever touches the (possibly
             # already donated-away) states.
+            @jax.named_scope("asd/sync")
             def _pack_sync(states):
                 info = jnp.stack(
                     [getattr(states, f).astype(jnp.int32) for f in _SYNC_ROWS]
@@ -499,6 +508,7 @@ class ShardWorker:
             self._weights_dev = jax.device_put(
                 self._weights_dev, NamedSharding(model_mesh, P()))
 
+        @jax.named_scope("asd/admit")
         def _admit(states, y0s, keys, idxs):
             # init + scatter for a whole boundary's admissions in ONE
             # dispatch; states donated — the scatter reuses the slot buffers
@@ -515,6 +525,9 @@ class ShardWorker:
 
         self._admit_fn = jax.jit(
             _admit, donate_argnums=(0,) if self._donate else ())
+        # padded admission widths ``_admit_fn`` has compiled (one program
+        # each): an admission at a new width is labelled a compile
+        self._admit_widths: set = set()
 
         # All slots start as already-finished dummy chains: frozen under
         # asd_round until a real request is admitted over them.
@@ -835,24 +848,40 @@ class ShardWorker:
             keys.append(keys[0])
         return idxs, y0s, keys
 
+    def _span(self, name: str, **args) -> span:
+        """A boundary span of this shard, on its lane of the ring."""
+        return span(self._tracer, name, pid=self.shard_id,
+                    tid=self.num_slots + _LANES[name],
+                    pname=f"shard-{self.shard_id}", tname=name, **args)
+
     def _admit_pending(self) -> None:
-        batch = self._collect_admissions(time.perf_counter())
-        if not batch:
-            return
-        idxs = [slot for slot, _, _, _ in batch]
-        y0s = [y0 for _, y0, _, _ in batch]
-        keys = [key for _, _, key, _ in batch]
-        if self.d_cond:
-            conds = np.array(self._conds)
-            for slot, _, _, cond_row in batch:
-                conds[slot] = cond_row
-        idxs, y0s, keys = self._pad_pow2(idxs, y0s, keys)
-        self._states = self._admit_fn(
-            self._states, jnp.stack(y0s), jnp.stack(keys),
-            jnp.asarray(idxs, jnp.int32),
-        )
-        if self.d_cond:
-            self._conds = jnp.asarray(conds)
+        with self._span("admit") as sp:
+            batch = self._collect_admissions(time.perf_counter())
+            if not batch:
+                return
+            idxs = [slot for slot, _, _, _ in batch]
+            y0s = [y0 for _, y0, _, _ in batch]
+            keys = [key for _, _, key, _ in batch]
+            if self.d_cond:
+                conds = np.array(self._conds)
+                for slot, _, _, cond_row in batch:
+                    conds[slot] = cond_row
+            idxs, y0s, keys = self._pad_pow2(idxs, y0s, keys)
+            width = len(idxs)
+            if sp.on:
+                sp.set(admitted=len(batch), width=width, rids=[
+                    self.scheduler.slot_info(slot).request.rid
+                    for slot, _, _, _ in batch])
+            cold = width not in self._admit_widths
+            self._admit_widths.add(width)
+            with (self._span("compile", program=f"admit[{width}]") if cold
+                  else contextlib.nullcontext()):
+                self._states = self._admit_fn(
+                    self._states, jnp.stack(y0s), jnp.stack(keys),
+                    jnp.asarray(idxs, jnp.int32),
+                )
+            if self.d_cond:
+                self._conds = jnp.asarray(conds)
 
     def _dispatch_superstep(self):
         """Admit at the boundary, launch one superstep, return its pending
@@ -868,27 +897,23 @@ class ShardWorker:
         # meetable requests — and drops are final.  _cache_size is a private
         # jax accessor: degrade to "warm" if an upgrade drops it
         cold = getattr(fn, "_cache_size", lambda: 1)() == 0
-        t0 = time.perf_counter()
-        if self._budget_as_data:
-            self._states, sync = fn(
-                self._states, self._conds, self._params, self._weights_dev,
-                np.int32(B))
-        else:
-            self._states, sync = fn(
-                self._states, self._conds, self._params, self._weights_dev)
-        t1 = time.perf_counter()
+        label = (self._span("compile", program=f"superstep[R={R},B={B}]")
+                 if cold else self._span("dispatch", R=R, budget=B))
+        with label:
+            t0 = time.perf_counter()
+            if self._budget_as_data:
+                self._states, sync = fn(
+                    self._states, self._conds, self._params,
+                    self._weights_dev, np.int32(B))
+            else:
+                self._states, sync = fn(
+                    self._states, self._conds, self._params,
+                    self._weights_dev)
+            t1 = time.perf_counter()
         if not cold:
             self.stats.dispatch_s += t1 - t0
         self.stats.rounds_total += R
         self.stats.supersteps += 1
-        tr = self._tracer
-        if tr is not None and tr.enabled:
-            tr.add_span(
-                "dispatch", t0, t1, pid=self.shard_id,
-                tid=self.num_slots, pname=f"shard-{self.shard_id}",
-                tname="dispatch",
-                args={"superstep": self.stats.supersteps, "R": R,
-                      "budget": B, "cold": cold})
         return (sync, self.stats.rounds_total, R, t0, cold)
 
     def _harvest(self, pending, done_at: Optional[float] = None) -> None:
@@ -908,15 +933,29 @@ class ShardWorker:
         tr = self._tracer
         if tr is not None and not tr.enabled:
             tr = None
-        t0 = time.perf_counter()
-        jax.block_until_ready(info_dev)  # waits on the device, off-path in
-        t1 = time.perf_counter()         # the double-buffered serve loops
+        with self._span("device_wait", R=R, cold=cold):
+            t0 = time.perf_counter()
+            jax.block_until_ready(info_dev)  # waits on the device, off-path
+            t1 = time.perf_counter()  # in the double-buffered serve loops
         self.stats.device_s += t1 - t0
-        if tr is not None:
-            tr.add_span(
-                "device_wait", t0, t1, pid=self.shard_id,
-                tid=self.num_slots + 1, pname=f"shard-{self.shard_id}",
-                tname="device", args={"R": R, "cold": cold})
+        with self._span("harvest") as sp:
+            finished = self._retire(info_dev, samples_dev, snapshot_rounds,
+                                    R, cold, tr)
+            self.stats.host_sync_s += time.perf_counter() - t1
+            self._refresh_health()
+            sp.set(retired=len(finished), live_demand=self._live_demand)
+        if not cold:  # a cold dispatch's elapsed time is mostly jit compile
+            # ``done_at``: a fused front end passes ONE completion stamp for
+            # the whole boundary, so later shards' EWMAs aren't inflated by
+            # their siblings' harvest bookkeeping running first
+            end = done_at if done_at is not None else time.perf_counter()
+            self._observe_round_time((end - t_dispatch) / R)
+
+    def _retire(self, info_dev, samples_dev, snapshot_rounds: int, R: int,
+                cold: bool, tr) -> list:
+        """The harvest's host side: read the sync packet, refresh the
+        live-demand signals, retire every finished chain.  Returns the
+        retired slots."""
         if self._collective_s_per_round and not cold:
             # calibrated estimate: the TP all-reduces run INSIDE the fused
             # superstep (one psum-probe wall per round, measured at init on
@@ -926,14 +965,6 @@ class ShardWorker:
                 R * self._collective_kind_s.get("psum", 0.0))
             self.stats.collective_a2a_s += (
                 R * self._collective_kind_s.get("all_to_all", 0.0))
-            if tr is not None:
-                # a view INTO device execution, anchored to end at the sync
-                # packet's readiness — the estimate, flagged as such
-                est = R * self._collective_s_per_round
-                tr.add_span(
-                    "collective", max(t1 - est, t_dispatch), t1,
-                    pid=self.shard_id, tid=self.num_slots + 3,
-                    tname="collective", args={"estimated": True, "R": R})
         info = np.asarray(jax.device_get(info_dev))
         row = {name: info[i] for i, name in enumerate(_SYNC_ROWS)}
         a, theta_live = row["a"], row["theta_live"]
@@ -976,12 +1007,14 @@ class ShardWorker:
                 if tr is not None:
                     rid = sinfo.request.rid
                     tr.add_span(
-                        "queued", sinfo.submit_time, sinfo.admit_time,
+                        "queued", tr.from_perf(sinfo.submit_time),
+                        tr.from_perf(sinfo.admit_time),
                         pid=self.shard_id, tid=slot,
                         pname=f"shard-{self.shard_id}",
                         tname=f"slot-{slot}", args={"rid": rid})
                     tr.add_span(
-                        "request", sinfo.admit_time, now,
+                        "request", tr.from_perf(sinfo.admit_time),
+                        tr.from_perf(now),
                         pid=self.shard_id, tid=slot,
                         args={"rid": rid,
                               "rounds": int(row["rounds"][slot]),
@@ -1014,21 +1047,7 @@ class ShardWorker:
             # next admission re-tiers from ITS OWN demand.
             self._live_demand = 0
             self._demand_ewma = 0.0
-        t_end = time.perf_counter()
-        self.stats.host_sync_s += t_end - t1
-        if tr is not None:
-            tr.add_span(
-                "harvest", t1, t_end, pid=self.shard_id,
-                tid=self.num_slots + 2, tname="harvest",
-                args={"retired": len(finished),
-                      "live_demand": self._live_demand})
-        self._refresh_health()
-        if not cold:  # a cold dispatch's elapsed time is mostly jit compile
-            # ``done_at``: a fused front end passes ONE completion stamp for
-            # the whole boundary, so later shards' EWMAs aren't inflated by
-            # their siblings' harvest bookkeeping running first
-            end = done_at if done_at is not None else time.perf_counter()
-            self._observe_round_time((end - t_dispatch) / R)
+        return finished
 
     def drain_results(self) -> dict:
         out, self._results = self._results, {}
@@ -1042,6 +1061,7 @@ class ShardWorker:
         self._make_superstep = warm._make_superstep
         self._superstep_fns = warm._superstep_fns
         self._admit_fn = warm._admit_fn
+        self._admit_widths = warm._admit_widths
         return self
 
     def chain_state(self, slot: int) -> ASDChainState:

@@ -1,8 +1,10 @@
 """Serving observability: trace ring/export, metrics registry/endpoints,
 health + drain signals, and the tracing-changes-nothing guarantees."""
 
+import glob
 import json
 import logging
+import re
 import urllib.error
 import urllib.request
 
@@ -19,6 +21,7 @@ from repro.serving.obs import (
     PROM_CONTENT_TYPE,
     TraceRecorder,
     instrument_engine,
+    span,
 )
 from repro.serving.sharded import ShardedASDEngine
 
@@ -169,6 +172,140 @@ class TestEngineTracing:
         assert all(s["pid"] == eng.num_shards for s in routes)
         fused = [s for s in tr.spans() if s["name"] == "fused_dispatch"]
         assert fused and all(s["pid"] == eng.num_shards for s in fused)
+
+
+# ---------------------------------------------------------------------------
+# Program spans and scopes in a profiler trace
+# ---------------------------------------------------------------------------
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under the profiler; returns the trace's ProfileData."""
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        fn()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    return ProfileData.from_file(path)
+
+
+def _host_events(pd, prefix="repro."):
+    """(name cut at '#', start_ns, end_ns) of the host events named
+    ``prefix...``, sorted by start."""
+    out = []
+    for plane in pd.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out.extend((e.name.split("#")[0], e.start_ns, e.end_ns)
+                           for e in line.events
+                           if e.name.startswith(prefix))
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+def _children(events, parent):
+    _, s, e = parent
+    return [c for c in events
+            if c is not parent and s <= c[1] and c[2] <= e]
+
+
+class TestProgramSpans:
+    def test_step_encloses_its_boundary_in_order(self, sl_model2, sched_tiny,
+                                                 tmp_path):
+        eng = _engine(sl_model2, sched_tiny)
+        for r in _requests(6):
+            eng.submit(r)
+
+        def drive():
+            for _ in range(4):
+                eng.step()
+
+        events = _host_events(_profiled(tmp_path, drive))
+        steps = [e for e in events if e[0] == "repro.step"]
+        assert len(steps) == 4
+        for i, st in enumerate(steps):
+            kids = _children(events, st)
+            # direct children: drop the compile nested inside admit
+            admit = [k for k in kids if k[0] == "repro.admit"]
+            assert len(admit) == 1
+            top = [k for k in kids if not any(
+                o is not k and o[1] <= k[1] and k[2] <= o[2] for o in kids)]
+            names = [k[0] for k in top]
+            launch = "repro.compile" if i == 0 else "repro.dispatch"
+            assert names == ["repro.admit", launch, "repro.device_wait",
+                             "repro.harvest"], names
+        # the cold first superstep compiles its program and the admission
+        # scatter's; later ones are warm
+        first = [k[0] for k in _children(events, steps[0])]
+        assert first.count("repro.compile") == 2
+        assert all("repro.compile" not in [k[0] for k in
+                                           _children(events, st)]
+                   for st in steps[1:])
+
+    def test_ring_and_profiler_share_a_clock(self, tmp_path):
+        tr = TraceRecorder()
+        rec = []
+
+        def once():
+            with span(tr, "probe", pid=0, tid=0):
+                rec.append(0)
+
+        pd = _profiled(tmp_path, once)
+        env = {k: v for p in pd.planes if p.name == "Task Environment"
+               for k, v in p.stats}
+        (name, start_ns, _), = _host_events(pd, "repro.probe")
+        ring, = [s for s in tr.spans() if s["name"] == "probe"]
+        trace_t0 = (env["profile_start_time"] + start_ns) * 1e-9
+        assert abs(trace_t0 - ring["t0"]) < 100e-6
+
+    def test_span_off_records_nothing(self):
+        tr = TraceRecorder(enabled=False)
+        with span(tr, "x", a=1) as sp:
+            sp.set(b=2)
+        with span(None, "y"):
+            pass
+        assert len(tr) == 0
+
+    def test_samples_identical_with_ring_and_profiler(
+            self, sl_model2, sched_tiny, tmp_path):
+        plain = _engine(sl_model2, sched_tiny)
+        golden = plain.serve(_requests(9))
+        ring = _engine(sl_model2, sched_tiny, tracer=TraceRecorder())
+        ring.adopt_programs(plain)
+        profiled = _engine(sl_model2, sched_tiny, tracer=TraceRecorder())
+        profiled.adopt_programs(plain)
+        got = {"ring": ring.serve(_requests(9))}
+        _profiled(tmp_path, lambda: got.update(
+            profiled=profiled.serve(_requests(9))))
+        for out in got.values():
+            assert out.keys() == golden.keys()
+            for rid in golden:
+                np.testing.assert_array_equal(out[rid], golden[rid])
+
+
+def _superstep_hlo(sl_model2, sched_tiny, **lane):
+    eng = _engine(sl_model2, sched_tiny, **lane)
+    R, B = eng._pick_rounds(), eng._pick_budget()
+    fn = eng._get_superstep(R, B)
+    extra = [np.int32(B)] if eng._budget_as_data else []
+    return fn.lower(eng._states, eng._conds, eng._params, eng._weights_dev,
+                    *extra).compile().as_text()
+
+
+@pytest.mark.parametrize("lane", [
+    dict(execution="unpacked", grs_impl="core"),
+    dict(execution="packed", round_impl="fused", pack_impl="kernel",
+         grs_impl="kernel", rounds_per_sync=2),
+], ids=["unpacked-core", "packed-fused-kernel"])
+def test_superstep_ops_carry_round_scopes(sl_model2, sched_tiny, lane):
+    hlo = _superstep_hlo(sl_model2, sched_tiny, **lane)
+    op_names = " ".join(re.findall(r'op_name="([^"]*)"', hlo))
+    for scope in ("asd/propose", "asd/verify", "asd/grs", "asd/commit",
+                  "asd/sync"):
+        assert scope in op_names, scope
+    if lane["execution"] == "packed":
+        assert "asd/pack" in op_names
+        # the kernels carry their stable names
+        assert "fused_gather" in hlo and "fused_verify_commit" in hlo
 
 
 # ---------------------------------------------------------------------------
