@@ -83,8 +83,8 @@ class EngineStats:
     rounds_total: int = 0  # fused engine rounds driven (all slots at once)
     supersteps: int = 0  # device dispatches (each runs rounds_per_sync rounds)
     # where the engine's wall time goes, per superstep boundary:
-    #   dispatch_s   host time spent launching the jitted superstep (+ the
-    #                admission dispatches) — the async call, not its execution
+    #   dispatch_s   host time spent launching the jitted superstep — the
+    #                async call, not its execution
     #   device_s     host time blocked waiting for a superstep's results to
     #                become ready (block_until_ready on the sync packet)
     #   host_sync_s  host time transferring the sync packet + retire/metrics
@@ -100,8 +100,14 @@ class EngineStats:
     #                It is a FRONT-END lane, never split across workers: the
     #                per-shard dispatch_s above must not invent per-shard
     #                launch time a worker never spent.
+    #   admit_s      host time admitting chains at a boundary: the
+    #                admission policy plus its one program per kind (the
+    #                fused front end's block rides on the merged view).
+    #                Taken before the superstep's launch, outside
+    #                dispatch_s, and kept out of the accounted total
     dispatch_s: float = 0.0
     fused_dispatch_s: float = 0.0
+    admit_s: float = 0.0
     device_s: float = 0.0
     host_sync_s: float = 0.0
     collective_s: float = 0.0
@@ -136,7 +142,8 @@ class EngineStats:
     # and pressure take the worst shard, occupancy averages, draining is any.
     _MERGE_SUM = (
         "requests", "retired", "batches", "rounds_total", "supersteps",
-        "dispatch_s", "fused_dispatch_s", "device_s", "host_sync_s",
+        "dispatch_s", "fused_dispatch_s", "admit_s", "device_s",
+        "host_sync_s",
         "collective_s", "collective_psum_s", "collective_a2a_s",
         "head_calls_total",
         "model_evals_total", "accepts_total", "proposals_total",
@@ -295,6 +302,7 @@ class EngineStats:
             "rounds_per_superstep": self.rounds_total / max(self.supersteps, 1),
             "dispatch_s": self.dispatch_s,
             "fused_dispatch_s": self.fused_dispatch_s,
+            "admit_s": self.admit_s,
             "device_s": self.device_s,
             "host_sync_s": self.host_sync_s,
             "collective_s": self.collective_s,

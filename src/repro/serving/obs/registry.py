@@ -225,6 +225,10 @@ class MetricsRegistry:
         return out
 
 
+_ADMIT_HELP = ("host seconds admitting chains at boundaries (admission "
+               "policy plus its device programs), outside the dispatch timer")
+
+
 def instrument_engine(registry: MetricsRegistry, engine) -> MetricsRegistry:
     """Register the serving metric catalog against a live engine.
 
@@ -299,6 +303,8 @@ def instrument_engine(registry: MetricsRegistry, engine) -> MetricsRegistry:
             "calibrated model-parallel collective seconds inside the "
             "superstep programs (view into device time)",
             fn=(lambda w=w: w.stats.collective_s), **lab)
+        registry.gauge("asd_admit_seconds", _ADMIT_HELP,
+                       fn=(lambda w=w: w.stats.admit_s), **lab)
         for kind, field in (("psum", "collective_psum_s"),
                             ("all_to_all", "collective_a2a_s")):
             registry.gauge(
@@ -313,4 +319,8 @@ def instrument_engine(registry: MetricsRegistry, engine) -> MetricsRegistry:
                 fn=(lambda w=w, q=q:
                     w.stats.latency_percentiles((q,))["completion"][f"p{q}"]),
                 quantile=f"p{q}", **lab)
+    if getattr(engine, "dispatch", None) == "fused":
+        # the fused front end admits for every shard in one block
+        registry.gauge("asd_admit_seconds", _ADMIT_HELP,
+                       fn=lambda: engine._admit_s, shard="frontend")
     return registry

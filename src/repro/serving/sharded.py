@@ -56,7 +56,6 @@ per-(R, budget) cache.
 
 from __future__ import annotations
 
-import contextlib
 import logging
 import time
 from typing import List, Optional
@@ -70,7 +69,12 @@ import numpy as np
 from repro.serving.metrics import EngineStats
 from repro.serving.obs.trace import span
 from repro.serving.router import LeastLoaded, Router
-from repro.serving.worker import _SYNC_ROWS, Request, ShardWorker
+from repro.serving.worker import (
+    _SYNC_ROWS,
+    Request,
+    ShardWorker,
+    dispatch_admissions,
+)
 
 __all__ = ["ShardedASDEngine"]
 
@@ -252,6 +256,9 @@ class ShardedASDEngine:
         # FRONT-END lane (EngineStats.fused_dispatch_s on the merged view),
         # never split across the workers' per-shard dispatch_s
         self._fused_dispatch_s = 0.0
+        # host seconds in the fused front end's admission block: a
+        # front-end lane too (EngineStats.admit_s on the merged view)
+        self._admit_s = 0.0
         self._tracer = worker_kwargs.get("tracer")
         self._routed = np.zeros((shards,), np.int64)  # router audit trail
         if fused:
@@ -338,26 +345,15 @@ class ShardedASDEngine:
         self._refresh_weights()
         self._fused_fns: dict = {}
 
-        from repro.core.asd import init_chain_state
-
         S_local, shards = w0.num_slots, self.num_shards
-        schedule, theta = w0.schedule, w0.theta
-        noise_mode, keep = w0.noise_mode, w0.keep_trajectory
-        controller = w0.controller
-        num_branches = w0.num_branches
-        branch_controller = w0.branch_controller
 
         @jax.named_scope("asd/admit")
         def _admit(states, y0s, keys, flat_idxs):
-            # one boundary's admissions for ALL shards: flatten the shard
-            # axis, scatter, restore — states donated, sharding re-pinned
-            # by out_shardings so the scatter cannot silently replicate
-            new = jax.vmap(
-                lambda y0, k: init_chain_state(
-                    schedule, y0, k, theta, noise_mode, keep, controller,
-                    num_branches=num_branches,
-                    branch_controller=branch_controller)
-            )(y0s, keys)
+            # one kind of a boundary's admissions for ALL shards: flatten
+            # the shard axis, scatter, restore — states donated, sharding
+            # re-pinned by out_shardings so the scatter cannot silently
+            # replicate
+            new = w0._fresh_chains(y0s, keys)
             flat = jax.tree_util.tree_map(
                 lambda x: x.reshape((shards * S_local,) + x.shape[2:]), states)
             upd = jax.tree_util.tree_map(
@@ -369,8 +365,9 @@ class ShardedASDEngine:
             _admit, donate_argnums=(0,) if w0._donate else (),
             out_shardings=jax.tree_util.tree_map(
                 lambda _: self._sharding, self._states))
-        # padded admission widths ``_fused_admit`` has compiled
-        self._fused_admit_widths: set = set()
+        # padded admission widths ``_fused_admit`` has compiled -> kinds
+        # (as ShardWorker._admit_widths)
+        self._fused_admit_widths: dict = {}
 
     def _refresh_weights(self) -> None:
         """Restack the per-shard allocator weights when any worker changed
@@ -468,39 +465,35 @@ class ShardedASDEngine:
                     tid=_FRONTEND_LANES[name], pname="frontend",
                     tname=name, **args)
 
-    def _dispatch_fused(self):
-        """One boundary for every shard: run each worker's admission policy,
-        scatter ALL placed chains in one fused dispatch, then launch ONE
-        shard_map superstep covering every shard."""
+    def _admit_fused(self) -> None:
+        """Run each worker's admission policy and admit ALL placed chains
+        in one program per kind (y0 drawn / given) across every shard."""
+        now = time.perf_counter()
         with self._span("admit") as sp:
-            now = time.perf_counter()
-            idxs, y0s, keys = [], [], []
+            rows = []
             S_local = self.workers[0].num_slots
             conds_touched = False
             for i, w in enumerate(self.workers):
                 for slot, y0, key, cond_row in w._collect_admissions(now):
-                    idxs.append(i * S_local + slot)
-                    y0s.append(y0)
-                    keys.append(key)
+                    rows.append((i * S_local + slot, y0, key))
                     if cond_row is not None:
                         self._conds_host[i, slot] = cond_row
                         conds_touched = True
-            if idxs:
-                admitted = len(idxs)
-                idxs, y0s, keys = ShardWorker._pad_pow2(idxs, y0s, keys)
-                width = len(idxs)
+            if rows:
+                self._states, width = dispatch_admissions(
+                    self._states, rows, self._fused_admit,
+                    self._fused_admit_widths, self._span, "fused_admit")
                 if sp.on:
-                    sp.set(admitted=admitted, width=width)
-                cold = width not in self._fused_admit_widths
-                self._fused_admit_widths.add(width)
-                with (self._span("compile", program=f"fused_admit[{width}]")
-                      if cold else contextlib.nullcontext()):
-                    self._states = self._fused_admit(
-                        self._states, jnp.stack(y0s), jnp.stack(keys),
-                        jnp.asarray(idxs, jnp.int32))
+                    sp.set(admitted=len(rows), width=width)
                 if conds_touched:
                     self._conds = jax.device_put(
                         jnp.asarray(self._conds_host), self._sharding)
+        self._admit_s += time.perf_counter() - now
+
+    def _dispatch_fused(self):
+        """One boundary for every shard: admit at it, then launch ONE
+        shard_map superstep covering every shard."""
+        self._admit_fused()
         self._refresh_weights()
         # one common R across shards: worker 0 picks, siblings follow
         # (their admission contexts must quantize consistently).  The
@@ -579,6 +572,7 @@ class ShardedASDEngine:
         m = EngineStats.merged(
             [w.stats for w in self.workers], wall_time=self._wall_time)
         m.fused_dispatch_s += self._fused_dispatch_s
+        m.admit_s += self._admit_s
         return m
 
     @property

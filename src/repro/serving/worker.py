@@ -115,6 +115,36 @@ def _pow2_ladder(lo: int, hi: int) -> tuple:
     return tuple(ladder)
 
 
+def dispatch_admissions(states, rows, admit_fn, compiled: dict, span_fn,
+                        program: str):
+    """Admit one boundary's placed chains ``rows = [(idx, y0, key)]`` into
+    ``states`` with one ``admit_fn(states, y0s, keys, idxs)`` dispatch per
+    kind: first the rows whose start state the program draws from the key
+    (``y0s`` None), then those that carry their own ``y0``.  The arguments
+    are stacked with NumPy on the host, padded to a power of two by
+    repeating row 0 (a duplicate scatter of the same record is a no-op) so
+    the program has O(log S) variants of each kind.  The first dispatch of
+    a (width, kind) is labelled a compile of ``program[width]``
+    (``compiled`` maps width -> kinds seen).  Returns the new states and
+    the width dispatched in all."""
+    total = 0
+    for drawn in (True, False):
+        part = [row for row in rows if (row[1] is None) == drawn]
+        if not part:
+            continue
+        width = 1 << (len(part) - 1).bit_length()
+        idxs, y0s, keys = zip(*(part + [part[0]] * (width - len(part))))
+        total += width
+        kinds = compiled.setdefault(width, set())
+        cold = drawn not in kinds
+        kinds.add(drawn)
+        with (span_fn("compile", program=f"{program}[{width}]") if cold
+              else contextlib.nullcontext()):
+            states = admit_fn(states, None if drawn else np.stack(y0s),
+                              np.stack(keys), np.asarray(idxs, np.int32))
+    return states, total
+
+
 class ShardWorker:
     """One shard's slot batch, superstep executables, and admission queue.
 
@@ -510,36 +540,27 @@ class ShardWorker:
 
         @jax.named_scope("asd/admit")
         def _admit(states, y0s, keys, idxs):
-            # init + scatter for a whole boundary's admissions in ONE
-            # dispatch; states donated — the scatter reuses the slot buffers
-            new_sts = jax.vmap(
-                lambda y0, k: init_chain_state(
-                    schedule, y0, k, self.theta, noise_mode, keep_trajectory,
-                    self.controller, num_branches=self.num_branches,
-                    branch_controller=self.branch_controller,
-                )
-            )(y0s, keys)
+            # draw + init + scatter for one kind of a boundary's admissions
+            # in ONE dispatch; states donated — the scatter reuses the slot
+            # buffers
+            new_sts = self._fresh_chains(y0s, keys)
             return jax.tree_util.tree_map(
                 lambda b, n: b.at[idxs].set(n), states, new_sts
             )
 
         self._admit_fn = jax.jit(
             _admit, donate_argnums=(0,) if self._donate else ())
-        # padded admission widths ``_admit_fn`` has compiled (one program
-        # each): an admission at a new width is labelled a compile
-        self._admit_widths: set = set()
+        # padded admission widths ``_admit_fn`` has compiled, each with the
+        # kinds compiled at it (True: y0 drawn in the program, False: y0
+        # given): an admission of a new (width, kind) is labelled a compile
+        self._admit_widths: dict = {}
 
         # All slots start as already-finished dummy chains: frozen under
         # asd_round until a real request is admitted over them.
         K = schedule.K
-        self._states = jax.vmap(
-            lambda k: init_chain_state(
-                schedule, jnp.zeros(self.event_shape), k, self.theta,
-                noise_mode, keep_trajectory, self.controller,
-                num_branches=self.num_branches,
-                branch_controller=self.branch_controller,
-            )
-        )(jax.random.split(jax.random.PRNGKey(seed), num_slots))
+        self._states = self._fresh_chains(
+            jnp.zeros((num_slots,) + self.event_shape),
+            jax.random.split(jax.random.PRNGKey(seed), num_slots))
         self._states = dataclasses.replace(
             self._states, a=jnp.full((num_slots,), K, jnp.int32)
         )
@@ -613,6 +634,23 @@ class ShardWorker:
         arrival order, or re-admitting one request, silently changed
         OTHER requests' samples."""
         return jax.random.fold_in(self._key, int(rid) & 0xFFFFFFFF)
+
+    def _fresh_chains(self, y0s, keys):
+        """Fresh chain states for one batch of admissions, traced inside an
+        admission program.  ``y0s`` None draws each row's start state from
+        its key first — ``key, k0 = split(key)``, ``y0 = init_y0(k0)`` —
+        the same bits a per-request draw on the host gives."""
+        def one(y0, key):
+            if y0 is None:
+                key, k0 = jax.random.split(key)
+                y0 = init_y0(self.schedule, k0, self.event_shape)
+            return init_chain_state(
+                self.schedule, y0, key, self.theta, self.noise_mode,
+                self.keep_trajectory, self.controller,
+                num_branches=self.num_branches,
+                branch_controller=self.branch_controller)
+
+        return jax.vmap(one)(y0s, keys)
 
     def _admission_context(self, now: float) -> AdmissionContext:
         return AdmissionContext(
@@ -794,7 +832,8 @@ class ShardWorker:
         """One boundary's admission POLICY + host bookkeeping, device-free:
         run the scheduler, account drops/weights/demand, and return the
         scatter batch ``[(slot, y0, key, cond_row)]`` (empty when nothing
-        was placed).  The caller owns the device scatter — per-worker
+        was placed), ``y0`` None where the admission program draws it from
+        the key.  The caller owns the device program — per-worker
         (``_admit_pending``) or fused across shards
         (``ShardedASDEngine._dispatch_fused``)."""
         placed = self.scheduler.admit(
@@ -810,11 +849,8 @@ class ShardWorker:
         for slot, req in placed:
             key = (req.key if req.key is not None
                    else self._request_key(req.rid))
-            if req.y0 is not None:
-                y0 = jnp.asarray(req.y0, jnp.float32)
-            else:
-                key, k0 = jax.random.split(key)
-                y0 = init_y0(self.schedule, k0, self.event_shape)
+            y0 = (None if req.y0 is None
+                  else np.asarray(req.y0, np.float32))
             cond_row = None
             if self.d_cond:
                 cond_row = (np.zeros((self.d_cond,), np.float32)
@@ -833,21 +869,6 @@ class ShardWorker:
             batch.append((slot, y0, key, cond_row))
         return batch
 
-    @staticmethod
-    def _pad_pow2(idxs, y0s, keys):
-        """Pad an admission batch to a power of two (duplicate scatter of
-        the same record is a no-op) so the jitted program has O(log S)
-        variants."""
-        n = len(idxs)
-        width = 1
-        while width < n:
-            width *= 2
-        while len(idxs) < width:
-            idxs.append(idxs[0])
-            y0s.append(y0s[0])
-            keys.append(keys[0])
-        return idxs, y0s, keys
-
     def _span(self, name: str, **args) -> span:
         """A boundary span of this shard, on its lane of the ring."""
         return span(self._tracer, name, pid=self.shard_id,
@@ -855,33 +876,24 @@ class ShardWorker:
                     pname=f"shard-{self.shard_id}", tname=name, **args)
 
     def _admit_pending(self) -> None:
+        t0 = time.perf_counter()
         with self._span("admit") as sp:
-            batch = self._collect_admissions(time.perf_counter())
-            if not batch:
-                return
-            idxs = [slot for slot, _, _, _ in batch]
-            y0s = [y0 for _, y0, _, _ in batch]
-            keys = [key for _, _, key, _ in batch]
-            if self.d_cond:
-                conds = np.array(self._conds)
-                for slot, _, _, cond_row in batch:
-                    conds[slot] = cond_row
-            idxs, y0s, keys = self._pad_pow2(idxs, y0s, keys)
-            width = len(idxs)
-            if sp.on:
-                sp.set(admitted=len(batch), width=width, rids=[
-                    self.scheduler.slot_info(slot).request.rid
-                    for slot, _, _, _ in batch])
-            cold = width not in self._admit_widths
-            self._admit_widths.add(width)
-            with (self._span("compile", program=f"admit[{width}]") if cold
-                  else contextlib.nullcontext()):
-                self._states = self._admit_fn(
-                    self._states, jnp.stack(y0s), jnp.stack(keys),
-                    jnp.asarray(idxs, jnp.int32),
-                )
-            if self.d_cond:
-                self._conds = jnp.asarray(conds)
+            batch = self._collect_admissions(t0)
+            if batch:
+                if self.d_cond:
+                    conds = np.array(self._conds)
+                    for slot, _, _, cond_row in batch:
+                        conds[slot] = cond_row
+                self._states, width = dispatch_admissions(
+                    self._states, [row[:3] for row in batch],
+                    self._admit_fn, self._admit_widths, self._span, "admit")
+                if sp.on:
+                    sp.set(admitted=len(batch), width=width, rids=[
+                        self.scheduler.slot_info(slot).request.rid
+                        for slot, _, _, _ in batch])
+                if self.d_cond:
+                    self._conds = jnp.asarray(conds)
+        self.stats.admit_s += time.perf_counter() - t0
 
     def _dispatch_superstep(self):
         """Admit at the boundary, launch one superstep, return its pending
