@@ -28,16 +28,17 @@ from chipbench import run as bench  # noqa: E402
 
 def control_numbers(cell: dict, pre: list) -> dict:
     from chipbench.check import compare, control_posts
-    from chipbench.model import make_weights
+    from chipbench.model import family, make_weights
     from chipbench.reference import ddpm_schedule, denoise_blocks
 
     cfg = cell["cfg"]
     w = make_weights(cfg)
-    m = cfg["model"]
+    m, denoise = cfg["model"], family(cfg).denoise
     sched = ddpm_schedule(int(cfg["K"]), cfg["beta_schedule"])
     theta, R = int(cfg["theta"]), int(cfg["lane"]["rounds_per_sync"])
-    low = lambda t, y: denoise_blocks(w, t, y, m, cfg["control"])  # noqa: E731
-    ref = lambda t, y: denoise_blocks(w, t, y, m)  # noqa: E731
+    low = lambda t, y: denoise_blocks(  # noqa: E731
+        denoise, w, t, y, m, cfg["control"])
+    ref = lambda t, y: denoise_blocks(denoise, w, t, y, m)  # noqa: E731
     return compare(pre, control_posts(pre, sched, theta, R, low), sched,
                    theta, R, ref)
 

@@ -1,6 +1,7 @@
 """The whole step's share of the chips' bf16 peak: the denoiser points the
 chains needed in the window (their model_evals counters) times the FLOPs of
-a point (chipbench/work.py), over the window's seconds, chips and peak."""
+a point (the ``flops`` of the configuration's family), over the window's
+seconds, chips and peak."""
 
 
 def read(run):

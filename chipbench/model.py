@@ -1,7 +1,14 @@
-"""A configuration's seeded weights and the keys drawn from ``--seed``.
+"""A configuration's model family, its seeded weights and the keys drawn
+from ``--seed``.
 
-The weights are the registry transformer's leaves, named and shaped as the
-configuration file states them, drawn on the device in one jitted call
+A configuration names its family under ``reference``: the module
+``families/<reference>.py``, which holds everything the harness knows of
+that architecture (the weights recipe, the plain reference's forward pass,
+the FLOPs of a point, what the reference assumes of the program's model).
+A new architecture is a new module there, found by that name.
+
+The weights are the family's leaves, named and shaped from the
+configuration's model section, drawn on the device in one jitted call
 from the configuration's own ``weights.seed``: every run of a cell serves
 the same model, and ``--seed`` changes only the requests' noise keys.
 Both the served program and the plain reference take their weights from
@@ -11,11 +18,38 @@ the program holds.
 
 from __future__ import annotations
 
+import functools
+import importlib.util
 import math
+import pathlib
 import zlib
 
 import jax
 import jax.numpy as jnp
+
+FAMILIES = pathlib.Path(__file__).resolve().parent / "families"
+
+
+def family(cfg: dict):
+    """The module of the configuration's family, ``FAMILIES/<reference>.py``."""
+    name = cfg["reference"]
+    path = FAMILIES / f"{name}.py"
+    if not path.is_file():
+        known = sorted(p.stem for p in FAMILIES.glob("*.py"))
+        raise SystemExit(f"{cfg['name']}: no model family {name!r} (no "
+                         f"{path}); known families: {known}")
+    return _load(str(path))
+
+
+@functools.lru_cache(maxsize=None)
+def _load(path: str):
+    """One module object per file, so that a family's jitted functions
+    compile once in a process."""
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench_family_{pathlib.Path(path).stem}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def base_key(seed: int) -> jax.Array:
@@ -24,31 +58,13 @@ def base_key(seed: int) -> jax.Array:
         jax.random.PRNGKey(seed & 0x7FFFFFFF), (seed >> 31) & 0x7FFFFFFF)
 
 
+def request_base(seed: int) -> jax.Array:
+    """The key that every request key of ``seed`` is folded from."""
+    return jax.random.fold_in(base_key(seed), 2)
+
+
 def request_key(seed: int, rid: int) -> jax.Array:
-    return jax.random.fold_in(jax.random.fold_in(base_key(seed), 2), rid)
-
-
-def leaf_shapes(m: dict) -> dict:
-    """{path: (shape, fan_in)} of the denoiser's weights; layers stacked."""
-    n, d, ff = m["n_layers"], m["d_model"], m["d_ff"]
-    h, kv, hd = m["n_heads"], m["n_kv_heads"], m["head_dim"]
-    C, td = m["d_data"], m["time_dim"]
-    return {
-        "in_proj": ((C, d), C),
-        "t_mlp1": ((td, d), td),
-        "t_mlp2": ((d, d), d),
-        "decoder/g0/attn_norm/scale": ((n, d), 0),
-        "decoder/g0/attn/wq": ((n, d, h, hd), d),
-        "decoder/g0/attn/wk": ((n, d, kv, hd), d),
-        "decoder/g0/attn/wv": ((n, d, kv, hd), d),
-        "decoder/g0/attn/wo": ((n, h, hd, d), h * hd),
-        "decoder/g0/ffn_norm/scale": ((n, d), 0),
-        "decoder/g0/ffn/w_gate": ((n, d, ff), d),
-        "decoder/g0/ffn/w_up": ((n, d, ff), d),
-        "decoder/g0/ffn/w_down": ((n, ff, d), ff),
-        "final_norm/scale": ((d,), 0),
-        "out_proj": ((d, C), d),
-    }
+    return jax.random.fold_in(request_base(seed), rid)
 
 
 def _nest(flat: dict) -> dict:
@@ -64,12 +80,17 @@ def _nest(flat: dict) -> dict:
 
 def make_weights(cfg: dict, sharding=None):
     """The configuration's weights, made on the device in one jitted call
-    from its ``weights.seed``, in the dtype they are served in."""
+    from its ``weights.seed``, in the dtype they are served in.
+
+    Each leaf of the family's ``leaf_shapes`` is drawn from its own key,
+    the seed's folded with the CRC-32 of its path, at lecun-normal scale
+    times ``weights["<path>_scale"]`` where that is given; a leaf whose
+    last name is in ``weights["zero"]`` is zero."""
     m, w = cfg["model"], cfg["weights"]
     if w["init"] != "lecun_normal":
         raise ValueError(f"unknown weight init {w['init']!r}")
     dtype = jnp.dtype(m["param_dtype"])
-    shapes = leaf_shapes(m)
+    shapes = family(cfg).leaf_shapes(m)
 
     def init(key):
         flat = {}
@@ -78,8 +99,8 @@ def make_weights(cfg: dict, sharding=None):
                 flat[path] = jnp.zeros(shape, dtype)
                 continue
             std = 1.0 / math.sqrt(fan_in)
-            if path == "out_proj":
-                std *= w["out_proj_scale"]
+            if f"{path}_scale" in w:
+                std *= w[f"{path}_scale"]
             k = jax.random.fold_in(key, zlib.crc32(path.encode()) & 0x7FFFFFFF)
             flat[path] = (std * jax.random.normal(k, shape, jnp.float32)
                           ).astype(dtype)

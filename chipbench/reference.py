@@ -1,15 +1,14 @@
-"""The plain reference: a float32 transformer denoiser, the DDPM schedule
-and one autospeculative round, written from their published descriptions.
+"""The plain reference: the DDPM schedule and one autospeculative round,
+written from their published descriptions, around the denoiser of the
+configuration's family (``chipbench/families/<reference>.py``).
 
-Nothing here imports the program.  The denoiser follows the registry
-transformer the configuration file describes (pre-RMSNorm blocks of
-self-attention and a SwiGLU MLP over the noisy tokens, a sinusoidal
-position and time embedding, an x0-predicting output projection).  The
-round follows the paper's Algorithm 1 with the Gaussian rejection sampler
-of its Algorithm 3 (arXiv:2505.03983): a proposal call at the chain's
-position, a theta-step rollout with the stale mean, one verification call
-over the theta points, the longest accepted prefix, and the reflected
-first rejection.  Every matrix product runs at ``precision=HIGHEST``.
+Nothing here imports the program.  The round follows the paper's
+Algorithm 1 with the Gaussian rejection sampler of its Algorithm 3
+(arXiv:2505.03983): a proposal call at the chain's position, a theta-step
+rollout with the stale mean, one verification call over the theta points,
+the longest accepted prefix, and the reflected first rejection.  A
+family's denoiser computes every matrix product through ``mm``, at
+``precision=HIGHEST``.
 
 ``lower`` computes the denoiser's matrix products with operands rounded to
 a lower precision (``bfloat16``, or ``float8_e4m3fn`` with one scale per
@@ -55,7 +54,7 @@ def ddpm_schedule(K: int, kind: str = "cosine") -> dict:
             "B": B[rev], "sigma": np.sqrt(var[rev]), "K": K}
 
 
-# ------------------------------------------------------------ the denoiser
+# ------------------------------------------------------------ matrix products
 
 
 def _round(x, dtype):
@@ -70,63 +69,19 @@ def _round(x, dtype):
     raise ValueError(dtype)
 
 
-def _mm(spec, a, b, lower):
+def mm(spec, a, b, lower=None):
+    """``einsum(spec, a, b)`` in float32 at ``HIGHEST``, its operands first
+    rounded to ``lower`` where it is given."""
     if lower:
         a, b = _round(a, lower), _round(b, lower)
     return jnp.einsum(spec, a.astype(jnp.float32), b.astype(jnp.float32),
                       precision=HIGHEST)
 
 
-def _sinusoid(pos, dim, max_period=1e4):
-    half = dim // 2
-    freqs = jnp.exp(-math.log(max_period) * jnp.arange(half) / half)
-    args = pos[..., None].astype(jnp.float32) * freqs
-    return jnp.concatenate([jnp.cos(args), jnp.sin(args)], axis=-1)
-
-
-def _rmsnorm(x, offset, eps):
-    x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-    return x * (1.0 + offset)
-
-
-@partial(jax.jit, static_argnames=("m", "lower"))
-def denoise(w, t, y, m, lower=None):
-    """x0_hat for points y (P, L, C) at model times t (P,).  ``m`` is the
-    configuration's model section as a tuple of items."""
-    m = dict(m)
-    mm = partial(_mm, lower=lower)
-    L, d, H, hd = m["seq_len"], m["d_model"], m["n_heads"], m["head_dim"]
-    rep = H // m["n_kv_heads"]
-    eps = m["norm_eps"]
-    temb = jnp.tanh(mm("pk,kd->pd", _sinusoid(t * 100.0, m["time_dim"]),
-                       w["t_mlp1"]))
-    temb = mm("pd,de->pe", temb, w["t_mlp2"])
-    x = mm("plc,cd->pld", y, w["in_proj"])
-    x = x + _sinusoid(jnp.arange(L), d)[None] + temb[:, None, :]
-
-    def layer(x, g):
-        h = _rmsnorm(x, g["attn_norm"]["scale"], eps)
-        q = mm("pld,dhk->plhk", h, g["attn"]["wq"])
-        k = mm("pld,dhk->plhk", h, g["attn"]["wk"])
-        v = mm("pld,dhk->plhk", h, g["attn"]["wv"])
-        k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
-        s = mm("plhk,pshk->phls", q, k) / math.sqrt(hd)
-        o = mm("phls,pshk->plhk", jax.nn.softmax(s, axis=-1), v)
-        x = x + mm("plhk,hkd->pld", o, g["attn"]["wo"])
-        h = _rmsnorm(x, g["ffn_norm"]["scale"], eps)
-        a = jax.nn.silu(mm("pld,df->plf", h, g["ffn"]["w_gate"]))
-        u = mm("pld,df->plf", h, g["ffn"]["w_up"])
-        return x + mm("plf,fd->pld", a * u, g["ffn"]["w_down"]), None
-
-    x, _ = jax.lax.scan(layer, x, w["decoder"]["g0"])  # one layer at a time
-    x = _rmsnorm(x, w["final_norm"]["scale"], eps)
-    return mm("pld,dc->plc", x, w["out_proj"])
-
-
-def denoise_blocks(w, t, y, m, lower=None, block=8):
-    """``denoise`` over a batch of points, ``block`` points at a time (a
-    lone point on its own), so that the reference fits beside nothing else
-    on the device and compiles at most two shapes."""
+def denoise_blocks(denoise, w, t, y, m, lower=None, block=8):
+    """A family's ``denoise`` over a batch of points, ``block`` points at a
+    time (a lone point on its own), so that the reference fits beside
+    nothing else on the device and compiles at most two shapes."""
     items = tuple(sorted(m.items()))
     out = []
     for i in range(0, len(t), block):

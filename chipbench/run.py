@@ -130,39 +130,44 @@ def serve_argv(cfg: dict, tr: dict) -> list:
     ]
 
 
-def denoiser_config(cfg: dict):
-    """The registry's denoiser family at the widths the configuration file
-    states; the program builds whatever sizes its config object holds."""
-    from dataclasses import replace
+def denoiser_config(cfg: dict, fam):
+    """The registry's ``registry_model`` with each key of the configuration's
+    model section written onto the field of that name, of its backbone or
+    of the ``DenoiserConfig``; the program builds whatever sizes its config
+    object holds.  Structured fields, such as the layer pattern, stay the
+    registry entry's."""
+    from dataclasses import fields, replace
 
     from repro.configs.registry import get_denoiser_config
 
     m = cfg["model"]
     dc = get_denoiser_config(cfg["registry_model"])
-    backbone = replace(
-        dc.backbone, n_layers=m["n_layers"], d_model=m["d_model"],
-        n_heads=m["n_heads"], n_kv_heads=m["n_kv_heads"],
-        head_dim=m["head_dim"], d_ff=m["d_ff"], norm_eps=m["norm_eps"],
-        compute_dtype=m["compute_dtype"], param_dtype=m["param_dtype"])
-    dc = replace(dc, backbone=backbone, seq_len=m["seq_len"],
-                 d_data=m["d_data"], time_dim=m["time_dim"])
-    check_model_matches(dc, cfg)
+    outer = {f.name for f in fields(dc)} - {"backbone"}
+    inner = {f.name for f in fields(dc.backbone)}
+    unknown = sorted(set(m) - outer - inner)
+    if unknown:
+        raise SystemExit(f"{cfg['name']}.json: model keys {unknown} name no "
+                         f"field of {cfg['registry_model']}'s config")
+    backbone = replace(dc.backbone, **{k: v for k, v in m.items()
+                                       if k in inner})
+    dc = replace(dc, backbone=backbone,
+                 **{k: v for k, v in m.items() if k in outer})
+    check_model_matches(dc, cfg, fam)
     return dc
 
 
-def check_model_matches(dc, cfg: dict) -> None:
-    """The program's model is the one the configuration file describes."""
-    b, m = dc.backbone, cfg["model"]
-    got = {"n_layers": b.n_layers, "d_model": b.d_model,
-           "n_heads": b.n_heads, "n_kv_heads": b.n_kv_heads,
-           "head_dim": b.resolved_head_dim, "d_ff": b.d_ff,
-           "seq_len": dc.seq_len, "d_data": dc.d_data,
-           "time_dim": dc.time_dim, "norm_eps": b.norm_eps,
-           "compute_dtype": b.compute_dtype, "param_dtype": b.param_dtype}
-    bad = {k: (v, m[k]) for k, v in got.items() if m[k] != v}
-    if bad or b.pos_embed != "none" or dc.d_cond or dc.time_log:
+def check_model_matches(dc, cfg: dict, fam) -> None:
+    """The program's model is the one the configuration file describes:
+    every key of its model section reads back, and what the family's
+    reference assumes beyond them holds."""
+    m = cfg["model"]
+    got = {k: getattr(dc.backbone if hasattr(dc.backbone, k) else dc, k)
+           for k in m}
+    bad = {k: (v, m[k]) for k, v in got.items() if v != m[k]}
+    departs = fam.program_departs(dc)
+    if bad or departs:
         raise SystemExit(f"{cfg['registry_model']} differs from "
-                         f"{cfg['name']}.json: {bad}")
+                         f"{cfg['name']}.json: {bad} {departs}")
 
 
 def build(cell: dict, devices: list):
@@ -170,14 +175,15 @@ def build(cell: dict, devices: list):
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from chipbench.model import make_weights
+    from chipbench.model import family, make_weights
     from repro.launch.serve import build_engine, build_parser, make_mesh
     from repro.models.diffusion import denoiser_init
     from repro.nn.param import unbox
 
     cfg, tr = cell["cfg"], cell["traffic"]
+    fam = family(cfg)
     args = build_parser().parse_args(serve_argv(cfg, tr))
-    dc = denoiser_config(cfg)
+    dc = denoiser_config(cfg, fam)
     mesh = make_mesh(args.mesh)
     params = make_weights(cfg, NamedSharding(mesh, P()))
     want = jax.eval_shape(lambda k: unbox(denoiser_init(k, dc)),
@@ -202,14 +208,18 @@ class Harness:
         import jax
         import numpy as np
 
-        from chipbench.model import request_key
+        from chipbench.model import request_base
 
         self.eng, self.tr = eng, tr
         self.K = int(cfg["K"])
         self.annotate = jax.profiler.TraceAnnotation
         self._np, self._jax = np, jax
-        self._keyfn = jax.jit(jax.vmap(lambda r: request_key(seed, r)))
-        self._poolfn = jax.jit(jax.vmap(lambda r: request_key(POOL_SEED, r)))
+        # the seeds are arguments, not constants: one program for every
+        # seed, found in the compilation cache after a checkout's first run
+        self._fold = jax.jit(jax.vmap(jax.random.fold_in, in_axes=(None, 0)))
+        self._seed_key = request_base(seed)
+        self._pool_key = request_base(POOL_SEED)
+        self._width = 1 << (int(tr["clients"]) - 1).bit_length()
         self.pool = int(tr.get("pooled_keys", 0))
         self.perm = np.random.default_rng(
             [seed & 0xFFFFFFFF, seed >> 32, 13]).permutation(self.pool)
@@ -222,12 +232,36 @@ class Harness:
         the seed."""
         np = self._np
         rids = np.asarray(rids, np.int64)
-        keys = np.array(self._keyfn(rids.astype(np.int32)))
+        keys = self._folded(self._seed_key, rids)
         pooled = rids < self.pool
         if pooled.any():
-            keys[pooled] = np.asarray(self._poolfn(
-                self.perm[rids[pooled]].astype(np.int32)))
+            keys[pooled] = self._folded(self._pool_key, self.perm[rids[pooled]])
         return keys
+
+    def _folded(self, base, ids) -> "np.ndarray":
+        """``base`` folded with each of ``ids``, the batch padded to the
+        traffic's number of clients (or the next power of two past a larger
+        batch) and the padding dropped: the warm-up's first batch compiles
+        the one program that every batch of the window runs."""
+        np = self._np
+        n = len(ids)
+        buf = np.zeros(max(self._width, 1 << max(n - 1, 0).bit_length()),
+                       np.int32)
+        buf[:n] = ids
+        return np.array(self._fold(base, buf))[:n]
+
+    def compile_admission(self, n: int) -> None:
+        """Compile the engine's program that admits ``n`` keyed requests at
+        a boundary, which the engine would otherwise compile when it first
+        admits ``n``.  The engine has no public call for this; one without
+        ``_admit_fn`` compiles the width there."""
+        fn = getattr(self.eng, "_admit_fn", None)
+        if fn is None:
+            log(f"no admission program to compile for {n} requests")
+            return
+        sds = self._jax.ShapeDtypeStruct
+        fn.lower(self.eng._states, None, sds((n, 2), self._np.uint32),
+                 sds((n,), self._np.int32)).compile()
 
     def submit(self, rids, keys=None) -> None:
         from repro.serving.engine import Request
@@ -310,13 +344,20 @@ def window_work(s0: dict, s1: dict, K: int) -> dict:
 def warm_up(d: Harness) -> None:
     """Compile (or load from the cache) every program the window will run.
 
-    Closed loops fill every slot at once, which is the only admission the
-    window can see before a chain finishes, and run two supersteps: the
-    admitting one and one as the window runs them.  An open
-    loop admits any number of requests at a boundary, which the engine pads
-    to a power of two: one wave of each size runs to completion."""
+    A boundary admits as many requests as it has free slots and queued
+    requests, which the engine pads to a power of two.  Closed loops fill
+    every slot at once and run two supersteps: the admitting one and one
+    as the window runs them; the admissions of fewer requests, which
+    follow a chain's end, are compiled without running.  An open loop runs
+    one wave of each power of two to completion.  Either loop's first
+    batch of requests compiles the program that draws every batch's
+    keys."""
     tr = d.tr
     if tr["loop"] == "closed":
+        n = 1
+        while n < int(tr["slots"]):
+            d.compile_admission(n)
+            n *= 2
         d.submit(list(range(int(tr["clients"]))))
         d.step()
         d.step()
@@ -468,13 +509,13 @@ def check_pairs(d: Harness) -> tuple:
 def run_check(cell: dict, pre: list, post: list) -> dict:
     """The reference's replay of ``pre`` compared with ``post``."""
     from chipbench.check import compare
-    from chipbench.model import make_weights
+    from chipbench.model import family, make_weights
     from chipbench.reference import ddpm_schedule, denoise_blocks
 
     cfg = cell["cfg"]
     w = make_weights(cfg)
-    m = cfg["model"]
-    model = lambda t, y: denoise_blocks(w, t, y, m)  # noqa: E731
+    m, denoise = cfg["model"], family(cfg).denoise
+    model = lambda t, y: denoise_blocks(denoise, w, t, y, m)  # noqa: E731
     sched = ddpm_schedule(int(cfg["K"]), cfg["beta_schedule"])
     return compare(pre, post, sched, int(cfg["theta"]),
                    int(cfg["lane"]["rounds_per_sync"]), model)
@@ -499,8 +540,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
              devices: list) -> dict:
     """One run of a cell on ``devices``: set-up, the window, the check.
     Returns the result object, the rows compared and the check's pairs."""
-    from chipbench import work
     from chipbench.check import judge
+    from chipbench.model import family
 
     w, cfg, tr = cell["workload"], cell["cfg"], cell["traffic"]
     eng, layout, params = build(cell, devices)
@@ -526,7 +567,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     kind = devices[0].device_kind
     run = dict(win, setup_s=setup_s, cfg=cfg, traffic=tr,
                chips=len(devices), peak=device_peaks(kind), layout=layout,
-               flops_per_point=work.denoiser_flops(cfg["model"]))
+               flops_per_point=family(cfg).flops(cfg["model"]))
     device = {"platform": devices[0].platform, "kind": kind,
               "count": len(devices), "memory_peak_bytes": int(peak)}
     result = {"correct": bool(correct)}

@@ -21,8 +21,8 @@ MODEL = {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 4,
          "param_dtype": "float32"}
 UNPACKED = {"execution": "unpacked", "round_impl": "packed",
             "pack_impl": "ref", "grs_impl": "core", "rounds_per_sync": 1}
-CLOSED = {"loop": "closed", "clients": 6, "slots": 4, "drain_s": 5,
-          "check_supersteps": 4}
+CLOSED = {"loop": "closed", "clients": 12, "slots": 4, "drain_s": 5,
+          "check_supersteps": 8}
 
 
 def cell(config: str = "policy", lane=None, traffic=None, K: int = 16):

@@ -1,5 +1,7 @@
 """Operations and bytes, computed from shapes, for the work the benchmark
-credits: one denoiser point and one call of each fused round kernel.
+credits to the round kernels: one call of each fused round kernel.  The
+FLOPs of one denoiser point are the model family's (``flops`` in
+``families/<reference>.py``).
 
 These are the algorithm's counts, not an implementation's: padding lanes,
 masked rows and recomputation are not counted, so a share of a peak built
@@ -9,25 +11,6 @@ on them can only come out low, never high.
 from __future__ import annotations
 
 F32 = 4  # bytes of a float32 / int32 element
-
-
-def denoiser_flops(cfg: dict) -> float:
-    """FLOPs of one denoiser point (one noisy sample at one step).
-
-    Every weight matrix costs 2 FLOPs per weight per token it multiplies;
-    self-attention adds 4 L^2 d per layer (QK^T and PV, all heads).  The
-    time embedding runs once per point.  Elementwise work (norms, SiLU,
-    softmax, residual adds) is left out.
-    """
-    L, C = cfg["seq_len"], cfg["d_data"]
-    d, ff, layers = cfg["d_model"], cfg["d_ff"], cfg["n_layers"]
-    q = cfg["n_heads"] * cfg["head_dim"]  # width of the query/output heads
-    kv = cfg["n_kv_heads"] * cfg["head_dim"]
-    per_layer = 2 * d * q + 2 * d * kv + 3 * d * ff  # q, o, k, v; gate, up, down
-    per_token = layers * per_layer + C * d + d * C  # blocks, in_proj, out_proj
-    attention = layers * 4 * L * L * q
-    time_embed = 2 * (cfg["time_dim"] * d + d * d)
-    return 2.0 * L * per_token + attention + time_embed
 
 
 def event_size(cfg: dict) -> int:
