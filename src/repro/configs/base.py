@@ -49,10 +49,15 @@ class ModelConfig:
     qkv_bias: bool = False
     rope_theta: float = 1e4
     pos_embed: str = "rope"  # rope | sinusoidal | none
-    # MoE
+    # MoE.  capacity_factor None: dropless, every token reaches its top_k
+    # experts (no capacity); norm_topk_prob: the top_k gates renormalized to
+    # sum to one; shared_ff: width of a shared SwiGLU expert that every token
+    # passes through beside its routed ones (0: none)
     n_experts: int = 0
     top_k: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: Optional[float] = 1.25
+    norm_topk_prob: bool = True
+    shared_ff: int = 0
     router_aux_weight: float = 0.01
     # SSM (mamba / hymba) and xLSTM
     ssm_state: int = 0
@@ -101,7 +106,7 @@ class ModelConfig:
             + self.n_heads * hd * d  # o
         )
         if self.n_experts:
-            ffn = self.n_experts * 3 * d * self.d_ff
+            ffn = self.n_experts * 3 * d * self.d_ff + 3 * d * self.shared_ff
         else:
             ffn = 3 * d * self.d_ff
         per_layer = attn + ffn
@@ -141,7 +146,8 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         top_k=min(cfg.top_k, 2),
         # generous capacity so token dropping can't bind at smoke scale —
         # keeps decode == forward exactly (drops are batch-context dependent)
-        capacity_factor=max(cfg.capacity_factor, 4.0),
+        capacity_factor=(None if cfg.capacity_factor is None
+                         else max(cfg.capacity_factor, 4.0)),
         n_vision_tokens=min(cfg.n_vision_tokens, 16),
         ssm_state=min(cfg.ssm_state, 8) if cfg.ssm_state else 0,
         compute_dtype="float32",

@@ -27,6 +27,24 @@ def paper_ldm_dit() -> DenoiserConfig:
     return DenoiserConfig(backbone=backbone, seq_len=1024, d_data=16)
 
 
+def paper_dit_moe_xl() -> DenoiserConfig:
+    """DiT-MoE-XL/2-8E2A (Fei et al., arXiv:2407.11633) over a 256x256
+    latent: DiT-XL/2's trunk (28 x 1152, 16 heads of 72) with every MLP a
+    dropless mixture of 8 SwiGLU experts of 4 x 1152, top-2 by a softmax
+    gate left unnormalized, beside a shared SwiGLU expert of 2 x 1152;
+    32x32x4 latent patches as 256 tokens of 16 channels.  Held in bfloat16:
+    the 3.94B parameters do not fit one v5e chip in float32."""
+    backbone = ModelConfig(
+        name="paper-dit-moe-xl", family="moe", n_layers=28, d_model=1152,
+        n_heads=16, n_kv_heads=16, head_dim=72, d_ff=4608, vocab_size=1,
+        group=(BlockDesc("attn", moe=True),),
+        n_experts=8, top_k=2, capacity_factor=None, norm_topk_prob=False,
+        shared_ff=2304, pos_embed="none", embed_inputs=False,
+        param_dtype="bfloat16",
+    )
+    return DenoiserConfig(backbone=backbone, seq_len=256, d_data=16)
+
+
 def paper_pixel_dit() -> DenoiserConfig:
     """Pixel-space stand-in for the LSUN-Church DDPM (paper §6.1, Fig 4):
     256x256x3 images as 1024 8x8-patch tokens."""
@@ -84,6 +102,7 @@ def qwen3_moe_a3b_smoke(action_dim: int = 4) -> DenoiserConfig:
 PAPER_MODELS = {
     "paper-ldm-dit": paper_ldm_dit,
     "paper-pixel-dit": paper_pixel_dit,
+    "paper-dit-moe-xl": paper_dit_moe_xl,
     "paper-diffusion-policy": paper_diffusion_policy,
     "paper-diffusion-policy-smoke": paper_diffusion_policy_smoke,
     "qwen3-moe-a3b-smoke": qwen3_moe_a3b_smoke,

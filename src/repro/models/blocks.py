@@ -30,7 +30,12 @@ def _maybe_ffn_init(key, cfg: ModelConfig, desc: BlockDesc):
         return {}
     k1, k2 = jax.random.split(key)
     if desc.moe:
-        return {"ffn_norm": rmsnorm_init(k1, cfg.d_model), "moe": moe_lib.moe_init(k2, cfg)}
+        p = {"ffn_norm": rmsnorm_init(k1, cfg.d_model),
+             "moe": moe_lib.moe_init(k2, cfg)}
+        if cfg.shared_ff:
+            p["shared_ffn"] = ffn_lib.ffn_init(
+                jax.random.fold_in(key, 2), cfg.d_model, cfg.shared_ff)
+        return p
     return {
         "ffn_norm": rmsnorm_init(k1, cfg.d_model),
         "ffn": ffn_lib.ffn_init(k2, cfg.d_model, cfg.d_ff, cfg.ffn_kind),
@@ -46,11 +51,17 @@ def _maybe_ffn_fwd(params, x, cfg: ModelConfig, desc: BlockDesc,
     # rank's Ulysses sequence slice — the dense FFN / norms are then
     # embarrassingly parallel (replicated weights, local rows) and the MoE
     # dispatch keeps the output local instead of psum-replicating it.
+    # A MoE block's shared expert (``shared_ffn``) takes every token beside
+    # its routed experts, as a dense FFN does.
     aux = {}
     if "moe" in params:
-        h, aux = moe_lib.moe_apply(
-            params["moe"], rmsnorm_apply(params["ffn_norm"], x), cfg,
-            ep_axis=ep_axis, seq_sharded=seq_sharded)
+        hn = rmsnorm_apply(params["ffn_norm"], x)
+        h, aux = moe_lib.moe_apply(params["moe"], hn, cfg, ep_axis=ep_axis,
+                                   seq_sharded=seq_sharded)
+        if "shared_ffn" in params:
+            with jax.named_scope("moe/shared"):
+                h = h + ffn_lib.ffn_apply(params["shared_ffn"], hn,
+                                          d_ff=cfg.shared_ff, tp_axis=tp_axis)
         x = x + h
     elif "ffn" in params:
         x = x + ffn_lib.ffn_apply(params["ffn"], rmsnorm_apply(params["ffn_norm"], x),

@@ -43,6 +43,9 @@ def denoiser_init(key, dc: DenoiserConfig):
     }
     if dc.d_cond:
         p["cond_proj"] = param(ks[6], (dc.d_cond, cfg.d_model), (None, "embed"))
+    dtype = jnp.dtype(cfg.param_dtype)
+    if dtype != jnp.float32:  # the weights are held in the model's dtype
+        p = jax.tree_util.tree_map(lambda a: a.astype(dtype), p)
     return p
 
 
@@ -254,7 +257,7 @@ def mp_collective_payloads(params, specs, dc: DenoiserConfig, *,
     Lt = dc.seq_len // mp_size if ep_exchanges else dc.seq_len
     E, k = cfg.n_experts, cfg.top_k
     cap = 0
-    if E:
+    if E and cfg.capacity_factor is not None:
         cap = min(int(max(1, -(-k * Lt * cfg.capacity_factor // E))), Lt)
 
     for path, leaf in flat_p:

@@ -1,11 +1,19 @@
-"""Mixture-of-Experts FFN: token-choice top-k routing with per-expert
-capacity (GShard-with-dropping semantics), TPU-native dispatch.
+"""Mixture-of-Experts FFN: token-choice top-k routing, TPU-native dispatch.
 
-Dispatch is the capacity-gather formulation: per expert, gather its top-C
-assigned tokens (no (N, E, C) one-hot blow-up), run a batched-over-experts
-SwiGLU, scatter-add back weighted by the (renormalized) router probs.
+A model with a ``capacity_factor`` keeps per-expert capacity (GShard-with-
+dropping semantics).  Its dispatch is the capacity-gather formulation: per
+expert, gather its top-C assigned tokens (no (N, E, C) one-hot blow-up),
+run a batched-over-experts SwiGLU, scatter-add back weighted by the router
+probs (renormalized over the top_k unless ``norm_topk_prob`` is off).
 
-Two execution layouts share the routing math:
+A model without one (``capacity_factor`` None) is dropless: every token
+reaches its top_k experts.  Its dispatch sorts the (token, expert) pairs by
+expert and runs the experts as grouped matmuls over exactly the routed rows
+(``repro.kernels.gmm``), so a call costs tokens x top_k expert rows however
+the router skews; the products are unsorted and summed with their gates.
+It runs on one device: it has no expert-parallel form.
+
+Two execution layouts share the capacity routing math:
 
   replicated   every device holds the full (E, d, ff) expert stacks and
                computes every expert (the train path and mp=1 serving).
@@ -25,10 +33,13 @@ Covers dbrx (E=16 top-4) and qwen3-moe (E=128 top-8 fine-grained d_ff=768).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels.gmm import gmm
 from repro.nn.param import param, normal_init
 
 
@@ -59,7 +70,8 @@ def _route(params, x, cfg: ModelConfig, capacity):
     logits = (x @ params["router"].astype(x.dtype)).astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)  # (B,L,E)
     top_p, top_idx = jax.lax.top_k(probs, k)  # (B,L,k)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    if cfg.norm_topk_prob:
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
 
     # sparse (B,L,E) weight matrix of the selected experts
     sel = jax.nn.one_hot(top_idx, E, dtype=jnp.float32)  # (B,L,k,E)
@@ -108,6 +120,12 @@ def moe_apply(params, x, cfg: ModelConfig, capacity: int | None = None,
     the rank's (B, L/mp, d) sequence slice (the Ulysses-composed path): the
     dispatch then skips its own token slice and the output stays local.
     """
+    if cfg.capacity_factor is None:
+        if ep_axis is not None:
+            raise ValueError(
+                f"{cfg.name}: a dropless MoE (capacity_factor None) has no "
+                "expert-parallel dispatch; serve it on one device per model")
+        return _moe_apply_dropless(params, x, cfg)
     E_local = params["w_gate"].shape[0]
     if ep_axis is not None and E_local != cfg.n_experts:
         return _moe_apply_ep(params, x, cfg, capacity, ep_axis, seq_sharded)
@@ -128,6 +146,68 @@ def moe_apply(params, x, cfg: ModelConfig, capacity: int | None = None,
     bidx = jnp.broadcast_to(jnp.arange(B)[:, None, None], token_idx.shape)
     out = out.at[bidx, token_idx].add(y_e)
     return out, {"moe_aux_loss": _aux_loss(ft, fp, cfg)}
+
+
+def _moe_apply_dropless(params, x, cfg: ModelConfig):
+    """Every token through its top_k experts (see ``_dropless``)."""
+    out, frac_tokens, frac_probs = _dropless(cfg)(params, x)
+    return out, {"moe_aux_loss": _aux_loss(frac_tokens, frac_probs, cfg)}
+
+
+@functools.lru_cache(maxsize=None)
+def _dropless(cfg: ModelConfig):
+    """The dropless layer for ``cfg``: (params, x (B, L, d)) -> (out, the
+    (E,) routed-token and router-prob fractions).
+
+    Tokens are independent, so a ``vmap`` over x (the engine maps the
+    model over its slots) is the same layer over all the batch's tokens at
+    once: its rule folds the mapped axis into B, so that one sort and one
+    set of grouped matmuls serve every slot.  The fractions are then
+    pooled over the batch."""
+    E, k = cfg.n_experts, cfg.top_k
+
+    @jax.custom_batching.custom_vmap
+    def layer(params, x):
+        B, L, d = x.shape
+        cdt = x.dtype
+        xf = x.reshape(B * L, d)
+        with jax.named_scope("moe/route"):
+            logits = (xf @ params["router"].astype(cdt)).astype(jnp.float32)
+            probs = jax.nn.softmax(logits, axis=-1)  # (N, E)
+            top_p, top_idx = jax.lax.top_k(probs, k)  # (N, k)
+            if cfg.norm_topk_prob:
+                top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True),
+                                            1e-9)
+        with jax.named_scope("moe/dispatch"):
+            expert = top_idx.reshape(-1)  # pair p: token p // k, its p % k
+            order = jnp.argsort(expert, stable=True)
+            sizes = jnp.bincount(expert, length=E).astype(jnp.int32)
+            rows = xf[order // k]  # (N * k, d), grouped by expert
+        with jax.named_scope("moe/experts"):
+            g = gmm(rows, params["w_gate"].astype(cdt), sizes)
+            u = gmm(rows, params["w_up"].astype(cdt), sizes)
+            h = jax.nn.silu(g.astype(jnp.float32)).astype(cdt) * u
+            y = gmm(h, params["w_down"].astype(cdt), sizes)  # (N * k, d)
+        with jax.named_scope("moe/combine"):
+            back = jnp.zeros_like(order).at[order].set(
+                jnp.arange(order.shape[0], dtype=order.dtype))
+            y = y[back].reshape(B * L, k, d).astype(jnp.float32)
+            out = (top_p[..., None] * y).sum(1).astype(cdt)
+        frac_tokens = jnp.mean(jax.nn.one_hot(top_idx, E).sum(1), axis=0)
+        return out.reshape(B, L, d), frac_tokens, probs.mean(0)
+
+    @layer.def_vmap
+    def _merged(axis_size, in_batched, params, x):
+        p_batched, x_batched = in_batched
+        if any(jax.tree_util.tree_leaves(p_batched)):
+            raise NotImplementedError(
+                "the dropless MoE layer maps over tokens, not weights")
+        if not x_batched:
+            return layer(params, x), (False, False, False)
+        out, ft, fp = layer(params, x.reshape((-1,) + x.shape[2:]))
+        return (out.reshape(x.shape), ft, fp), (True, False, False)
+
+    return layer
 
 
 def _moe_apply_ep(params, x, cfg: ModelConfig, capacity, ep_axis: str,

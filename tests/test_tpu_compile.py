@@ -6,7 +6,8 @@ lower each kernel through its public wrapper for one chip of a described
 ``v5e:2x2`` topology and compile it with the installed TPU compiler — no
 chip is attached, nothing runs.  Widths are those of ``paper-ldm-dit``:
 a 1024 x 16 latent event, 16 slots x theta 32 = 512 window rows, and
-attention over 1024 tokens with 16 heads of 72.
+attention over 1024 tokens with 16 heads of 72; the grouped matmul at
+``paper-dit-moe-xl``'s expert widths.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and the test workers all import this
@@ -22,6 +23,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.flash_attention.ops import flash_mha
+from repro.kernels.gmm import gmm
 from repro.kernels.grs.ops import grs
 from repro.kernels.pack import gather_rows, scatter_rows
 from repro.kernels.superstep import fused_gather, fused_verify_commit
@@ -29,6 +31,9 @@ from repro.kernels.superstep import fused_gather, fused_verify_commit
 EVENT = (1024, 16)  # paper-ldm-dit latent: tokens x channels
 ROWS = 512  # window rows: 16 slots x theta 32
 HEADS, HEAD_DIM, SEQ = 16, 72, 1024
+# paper-dit-moe-xl's expert products: 16 slots x 9 points x 256 tokens x
+# top-2 = 73,728 routed rows, 8 experts of 1152 -> 4608 -> 1152
+MOE_ROWS, EXPERTS, D_MODEL, D_FF = 73728, 8, 1152, 4608
 
 
 @pytest.fixture(scope="module")
@@ -104,3 +109,11 @@ def test_flash_mha_compiles(one_chip):
     _compile(lambda q, k, v: flash_mha(q, k, v, causal=False,
                                        interpret=False),
              qkv(), qkv(), qkv())
+
+
+@pytest.mark.parametrize("k, n", [(D_MODEL, D_FF), (D_FF, D_MODEL)])
+def test_gmm_compiles(one_chip, k, n):
+    _compile(lambda x, w, s: gmm(x, w, s, interpret=False),
+             _arg(one_chip, (MOE_ROWS, k), jnp.bfloat16),
+             _arg(one_chip, (EXPERTS, k, n), jnp.bfloat16),
+             _arg(one_chip, (EXPERTS,), jnp.int32))
