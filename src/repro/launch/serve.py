@@ -54,8 +54,8 @@ adaptive windows save wall-clock, not just counted work:
 
 Device-resident supersteps: fuse R speculation rounds per dispatch (the
 slot-state pytree is donated to XLA and updated in place; the host only
-syncs retire flags at superstep boundaries, double-buffered off the
-critical path):
+syncs retire flags at superstep boundaries, off the critical path while
+no request waits for a slot):
 
   --rounds-per-sync 4      fixed superstep length
   --rounds-per-sync auto   accept-rate-adaptive R on a power-of-two ladder

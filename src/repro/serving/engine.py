@@ -10,19 +10,24 @@ slowest chain and padded lanes burn compute.
 ``ContinuousASDEngine`` — the continuous-batching engine: ONE
 ``repro.serving.worker.ShardWorker`` (which owns the slot batch, the donated
 superstep executables, the sync-packet harvest, and the admission queue)
-plus the host serve loop.  The worker drives device-resident SUPERSTEPS:
-each dispatch runs ``rounds_per_sync`` fused speculation rounds under a
-``lax.scan`` (chains that finish mid-superstep become masked no-ops,
+plus the host boundary ``step()``.  The worker drives device-resident
+SUPERSTEPS: each dispatch runs ``rounds_per_sync`` fused speculation rounds
+under a ``lax.scan`` (chains that finish mid-superstep become masked no-ops,
 bit-for-bit frozen), with the slot-state pytree DONATED to XLA so buffers
 are reused in place instead of copied per round.  The host is a lazy
-scheduler that only intervenes at superstep boundaries: it dispatches
-superstep s+1 immediately, then harvests superstep s's compact sync packet
-(retire flags, counters, samples — one small transfer, no per-slot peeks)
-while the device runs — ``block_until_ready`` never sits on the critical
-path.  A chain that commits its final step retires at the next boundary and
-its slot is refilled from the queue (FCFS, see ``repro.serving.scheduler``).
-Each round is ONE fused (slots x theta)-point verification forward — on a
-mesh it is pjit-sharded over the `data` axis (see repro/launch/serve.py).
+scheduler that only intervenes at superstep boundaries, and each
+boundary is double-buffered (``repro.serving.worker.step_lanes``): it
+admits and launches superstep s+1, then harvests superstep s's compact sync
+packet (retire flags, counters, samples — one small transfer, no per-slot
+peeks) while the device runs s+1.  The engine picks the order from its
+queue: while a request waits for a slot, the boundary harvests s before it
+admits and s+1 before it returns, so a slot freed by superstep s is
+refilled at boundary s+1; with nobody waiting, ``step()`` returns with s+1
+still in flight.  A chain that commits its final step retires at the next
+boundary and its slot is refilled from the queue (FCFS, see
+``repro.serving.scheduler``).  Each round is ONE fused (slots x theta)-point
+verification forward — on a mesh it is pjit-sharded over the `data` axis
+(see repro/launch/serve.py).
 
 The continuous engine is parameterized on two pluggable axes:
 
@@ -60,7 +65,7 @@ from repro.core.schedules import Schedule
 from repro.core.sequential import sequential_sample, init_y0
 from repro.models.diffusion import DenoiserConfig
 from repro.serving.metrics import EngineStats
-from repro.serving.worker import Request, ShardWorker
+from repro.serving.worker import Request, ShardWorker, step_lanes
 
 __all__ = ["ASDServingEngine", "ContinuousASDEngine", "Request"]
 
@@ -72,7 +77,7 @@ __all__ = ["ASDServingEngine", "ContinuousASDEngine", "Request"]
 
 class ContinuousASDEngine(ShardWorker):
     """Slot-based continuous-batching ASD server: one ``ShardWorker`` plus
-    the double-buffered host serve loop.
+    the double-buffered host boundary ``step()``.
 
     All constructor arguments are the worker's — see
     ``repro.serving.worker.ShardWorker`` for the full reference
@@ -90,36 +95,28 @@ class ContinuousASDEngine(ShardWorker):
         self.scheduler.submit(request, time.perf_counter())
 
     def step(self) -> bool:
-        """Admit, run ONE superstep (``rounds_per_sync`` fused rounds) over
-        all slots, harvest its boundary synchronously.
+        """One superstep boundary: admit, launch superstep s+1
+        (``rounds_per_sync`` fused rounds over all slots) if there is work,
+        and harvest superstep s while s+1 runs.
 
-        Returns True while there is still work queued or in flight.  This is
-        the synchronous drive used by open-loop arrival simulators; batch
-        serving should prefer ``serve()``, whose double-buffered loop keeps
-        the device busy while the host harvests.
+        The call returns with s+1 still in flight unless a request is left
+        queued after this boundary's admissions; then it harvests s+1 too,
+        so the next boundary refills the slots s+1 frees (and, with more
+        requests queued than free slots, a boundary harvests s before it
+        admits).  Chains retired in s are in ``drain_results()`` when the
+        call returns.  Returns True while work is queued, in a slot or in
+        flight: ``while eng.step(): pass`` ends with every result harvested
+        and nothing in flight.
         """
-        if not self.scheduler.has_work():
+        if not self.has_work():
             return False
-        with self._span("step", superstep=self.stats.supersteps):
-            self._harvest(self._dispatch_superstep())
-        return self.scheduler.has_work()
+        with self._span("step", superstep=self.stats.supersteps) as sp:
+            sp.set(overlap=step_lanes([self._lane]))
+        return self.has_work()
 
     def serve(self, requests: list[Request], key=None) -> dict[int, np.ndarray]:
-        """Submit everything, drive supersteps until drained, return
-        {rid: sample}.
-
-        The loop is double-buffered: superstep s+1 is dispatched BEFORE
-        superstep s's sync packet is read back, so the blocking harvest
-        (device wait + transfer + retire bookkeeping) overlaps the device's
-        next R rounds instead of serializing with them —
-        ``block_until_ready`` never sits on the critical path.  The one
-        exception is deliberate: while requests are QUEUED waiting for a
-        slot, the boundary harvests synchronously instead, so a slot freed
-        by superstep s refills at boundary s+1 rather than s+2 — occupancy
-        is worth more than overlap when someone is waiting.  With an empty
-        queue the lag is free (nobody wants the slot) and the harvest rides
-        fully off the critical path.
-        """
+        """Submit everything, ``step()`` until drained, return
+        {rid: sample}."""
         if key is not None:
             self._key = key
         self.dropped_rids = []  # drops are reported per serve() wave
@@ -128,21 +125,8 @@ class ContinuousASDEngine(ShardWorker):
         t0 = time.perf_counter()
         for r in requests:
             self.submit(r)
-        pending = None
-        while self.scheduler.has_work() or pending is not None:
-            with self._span("step", superstep=self.stats.supersteps):
-                if pending is not None and self.scheduler.queue_depth > 0:
-                    # someone is waiting for a slot: sync the boundary so
-                    # the dispatch below can admit into lanes superstep s
-                    # freed
-                    self._harvest(pending)
-                    pending = None
-                nxt = None
-                if self.scheduler.has_work():
-                    nxt = self._dispatch_superstep()
-                if pending is not None:
-                    self._harvest(pending)  # overlaps the dispatch in flight
-                pending = nxt
+        while self.step():
+            pass
         jax.block_until_ready(self._states.a)
         self.stats.wall_time += time.perf_counter() - t0
         self._refresh_health()

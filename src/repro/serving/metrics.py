@@ -82,6 +82,9 @@ class EngineStats:
     batches: int = 0  # chunked engine: batches launched
     rounds_total: int = 0  # fused engine rounds driven (all slots at once)
     supersteps: int = 0  # device dispatches (each runs rounds_per_sync rounds)
+    # supersteps launched while their predecessor was still to be harvested
+    # (the boundary overlapped: nobody was waiting for a slot)
+    overlapped_supersteps: int = 0
     # where the engine's wall time goes, per superstep boundary:
     #   dispatch_s   host time spent launching the jitted superstep — the
     #                async call, not its execution
@@ -142,8 +145,8 @@ class EngineStats:
     # and pressure take the worst shard, occupancy averages, draining is any.
     _MERGE_SUM = (
         "requests", "retired", "batches", "rounds_total", "supersteps",
-        "dispatch_s", "fused_dispatch_s", "admit_s", "device_s",
-        "host_sync_s",
+        "overlapped_supersteps", "dispatch_s", "fused_dispatch_s", "admit_s",
+        "device_s", "host_sync_s",
         "collective_s", "collective_psum_s", "collective_a2a_s",
         "head_calls_total",
         "model_evals_total", "accepts_total", "proposals_total",

@@ -255,6 +255,9 @@ def instrument_engine(registry: MetricsRegistry, engine) -> MetricsRegistry:
              lambda w: w.stats.rounds_total),
             ("asd_supersteps_total", "device superstep dispatches",
              lambda w: w.stats.supersteps),
+            ("asd_overlapped_supersteps_total",
+             "supersteps launched before their predecessor was harvested",
+             lambda w: w.stats.overlapped_supersteps),
         ]
         for name, help_text, fn in counters:
             registry.counter(name, help_text,

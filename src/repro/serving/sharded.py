@@ -24,7 +24,7 @@ and its admission queue defers or drops under ITS OWN budget pressure.
 Two dispatch shapes drive the shards:
 
   ``dispatch="per-shard"``   each worker launches its own superstep program
-      (the serve loop dispatches all shards back-to-back before harvesting
+      (a boundary dispatches all shards back-to-back before harvesting
       any); shards may run DIFFERENT budget tiers and superstep lengths and
       live on any device layout.
   ``dispatch="fused"``       every shard's superstep runs in ONE
@@ -71,9 +71,11 @@ from repro.serving.obs.trace import span
 from repro.serving.router import LeastLoaded, Router
 from repro.serving.worker import (
     _SYNC_ROWS,
+    Lane,
     Request,
     ShardWorker,
     dispatch_admissions,
+    step_lanes,
 )
 
 __all__ = ["ShardedASDEngine"]
@@ -263,6 +265,11 @@ class ShardedASDEngine:
         self._routed = np.zeros((shards,), np.int64)  # router audit trail
         if fused:
             self._init_fused(devices)
+        # one pending record per dispatch lane: each worker's own, or the
+        # fused front end's for every shard at once
+        self._lanes = ([Lane(self._dispatch_fused, self._harvest_fused,
+                             self.workers)] if fused
+                       else [w._lane for w in self.workers])
         log.debug("sharded engine up: %d shards x %d slots, dispatch=%s, "
                   "router=%s, mp=%d", shards, slots_local, dispatch,
                   self.router.name, mp)
@@ -591,7 +598,10 @@ class ShardedASDEngine:
         return self._routed.copy()
 
     def has_work(self) -> bool:
-        return any(w.has_work() for w in self.workers)
+        """Whether any shard has work queued or in a slot, or a superstep
+        is still in flight."""
+        return (any(w.scheduler.has_work() for w in self.workers)
+                or any(ln.pending is not None for ln in self._lanes))
 
     @property
     def draining(self) -> bool:
@@ -649,36 +659,25 @@ class ShardedASDEngine:
         self.workers[shard].scheduler.submit(request, now)
 
     def step(self) -> bool:
-        """One superstep boundary across every shard with work: dispatch all
-        (their device programs overlap), then harvest all synchronously.
-        Returns True while any shard still has work — the open-loop drive."""
+        """One superstep boundary across every shard with work, ordered as
+        ``ContinuousASDEngine.step``: all launches of superstep s+1 (one
+        per shard, or the one fused program) go out before any harvest of
+        s, and each lane keeps s+1 in flight unless one of its shards has a
+        request left queued.  Returns True while any shard has work queued,
+        in a slot or in flight."""
         if not self.has_work():
             return False
-        with self._span("step", superstep=self._supersteps()):
-            if self.dispatch == "fused":
-                self._harvest_fused(self._dispatch_fused())
-            else:
-                pending = [(w, w._dispatch_superstep())
-                           for w in self.workers if w.has_work()]
-                for w, rec in pending:
-                    w._harvest(rec)
+        with self._span("step", superstep=self._supersteps()) as sp:
+            sp.set(overlap=step_lanes(self._lanes))
         return self.has_work()
 
     def _supersteps(self) -> int:
         return max(w.stats.supersteps for w in self.workers)
 
     def serve(self, requests: List[Request], key=None) -> dict:
-        """Submit everything through the router, drive all shards until
-        drained, return {rid: sample}.
-
-        The loop generalizes the single-shard double-buffering: at each
-        boundary every working shard's superstep s+1 is dispatched (in shard
-        order, so the N device programs are all in flight) BEFORE any shard's
-        superstep-s packet is harvested; a shard with queued requests
-        harvests first so freed slots refill at this boundary (occupancy over
-        overlap when someone waits).  With shards=1 this is exactly
-        ``ContinuousASDEngine.serve``.
-        """
+        """Submit everything through the router, ``step()`` until every
+        shard has drained, return {rid: sample}.  With shards=1 this is
+        exactly ``ContinuousASDEngine.serve``."""
         if key is not None:
             # every worker shares the SAME serve key: unkeyed requests
             # derive theirs as fold_in(key, rid), a pure function of the
@@ -693,36 +692,11 @@ class ShardedASDEngine:
         t0 = time.perf_counter()
         for r in requests:
             self.submit(r)
+        while self.step():
+            pass
         if self.dispatch == "fused":
-            # one pending record covers every shard: the fused program IS
-            # the boundary, double-buffered exactly like the single engine
-            fpending = None
-            while self.has_work() or fpending is not None:
-                with self._span("step", superstep=self._supersteps()):
-                    if fpending is not None and any(
-                            w.scheduler.queue_depth > 0
-                            for w in self.workers):
-                        self._harvest_fused(fpending)
-                        fpending = None
-                    nxt = self._dispatch_fused() if self.has_work() else None
-                    if fpending is not None:
-                        self._harvest_fused(fpending)
-                    fpending = nxt
             jax.block_until_ready(self._states.a)
         else:
-            pending: dict[int, tuple] = {}
-            while self.has_work() or pending:
-                with self._span("step", superstep=self._supersteps()):
-                    for i, w in enumerate(self.workers):
-                        if i in pending and w.scheduler.queue_depth > 0:
-                            w._harvest(pending.pop(i))
-                    nxt = {}
-                    for i, w in enumerate(self.workers):
-                        if w.has_work():
-                            nxt[i] = w._dispatch_superstep()
-                    for i in sorted(pending):
-                        self.workers[i]._harvest(pending.pop(i))
-                    pending = nxt
             for w in self.workers:
                 jax.block_until_ready(w._states.a)
         self._wall_time += time.perf_counter() - t0

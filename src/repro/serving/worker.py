@@ -145,6 +145,66 @@ def dispatch_admissions(states, rows, admit_fn, compiled: dict, span_fn,
     return states, total
 
 
+@dataclasses.dataclass(eq=False)
+class Lane:
+    """One double-buffered dispatch lane: ``dispatch()`` admits and launches
+    a superstep and returns its harvest record, ``harvest(record)`` consumes
+    it, ``workers`` are the shards whose queues and slots the lane serves,
+    and ``pending`` is the record of the superstep still in flight."""
+
+    dispatch: Callable
+    harvest: Callable
+    workers: list
+    pending: Optional[tuple] = None
+
+    def waiting(self, past_free: bool) -> bool:
+        """Whether a request is queued on one of the lane's shards (beyond
+        its free slots, with ``past_free``)."""
+        return any(
+            w.scheduler.queue_depth
+            > (len(w.scheduler.free_slots()) if past_free else 0)
+            for w in self.workers)
+
+    def flush(self) -> None:
+        if self.pending is not None:
+            rec, self.pending = self.pending, None
+            self.harvest(rec)
+
+
+def step_lanes(lanes: list) -> int:
+    """One superstep boundary over ``lanes``, double-buffered where nobody
+    waits for a slot.  Returns how many lanes launched superstep s+1 before
+    harvesting s.
+
+    1. A lane with s in flight and more requests queued than free slots
+       harvests s first, so the slots s freed are refilled at this boundary.
+    2. Every lane whose scheduler has work admits and launches s+1 (all of
+       them before any harvest, so the device programs are all in flight).
+    3. Each lane harvests s while s+1 runs.
+    4. A lane that still has a request queued after this boundary's
+       admissions harvests s+1 too, so its slots refill at the next
+       boundary; otherwise s+1 stays in flight until the next call.
+    """
+    for ln in lanes:
+        if ln.waiting(past_free=True):
+            ln.flush()
+    launched = [ln.dispatch() if any(w.scheduler.has_work()
+                                     for w in ln.workers) else None
+                for ln in lanes]
+    overlapped = 0
+    for ln, rec in zip(lanes, launched):
+        if ln.pending is not None and rec is not None:
+            overlapped += 1
+            for w in ln.workers:
+                w.stats.overlapped_supersteps += 1
+        ln.flush()
+        ln.pending = rec
+    for ln in lanes:
+        if ln.waiting(past_free=False):
+            ln.flush()
+    return overlapped
+
+
 class ShardWorker:
     """One shard's slot batch, superstep executables, and admission queue.
 
@@ -212,7 +272,7 @@ class ShardWorker:
       donate: donate the slot-state pytree to the superstep/admit dispatches
         (in-place buffer reuse).  Default (None): on for every backend
         EXCEPT cpu — the CPU PJRT runtime runs donated executions
-        synchronously, which serializes the double-buffered serve loop and
+        synchronously, which serializes the double-buffered boundary and
         books device execution time as dispatch time.
       rounds_per_sync: speculation rounds fused per device dispatch (the
         SUPERSTEP length R), or "auto" for the accept-rate ladder.
@@ -237,8 +297,8 @@ class ShardWorker:
         next to request-lifecycle spans (queued + request per slot), against
         pid = shard_id.  Tracing adds no device syncs.  ``None`` (default):
         the profiler spans alone.
-      pipelined: deprecated alias kept for compatibility — the serve loops
-        are always double-buffered; the flag is ignored.
+      pipelined: deprecated alias kept for compatibility; ignored (every
+        boundary overlaps when nobody waits for a slot, see ``step_lanes``).
     """
 
     def __init__(
@@ -321,7 +381,7 @@ class ShardWorker:
         self.round_impl = round_impl
         # donation makes the CPU runtime execute dispatches synchronously
         # (the aliased input buffer must be finalized before the call
-        # returns), so the double-buffered loops lose their overlap and the
+        # returns), so the double-buffered boundary loses its overlap and the
         # dispatch timer absorbs the whole device execution — default it off
         # there, on everywhere else (TPU dispatch stays async)
         self._donate = (
@@ -377,6 +437,12 @@ class ShardWorker:
                     f"{rounds_per_sync!r}")
         self.scheduler = SlotScheduler(num_slots, policy=policy)
         self.stats = EngineStats(shard=shard_id)
+        # this worker's dispatch lane (``step_lanes``); a fused front end
+        # drives the workers through its own lane instead
+        self._lane = Lane(self._dispatch_superstep, self._harvest, [self])
+        # when the last harvested superstep was seen complete: a superstep
+        # launched behind it starts on the device no earlier
+        self._done_at = 0.0
         self._key = jax.random.PRNGKey(seed)
         self._results: dict[int, np.ndarray] = {}
         self.dropped_rids: list[int] = []
@@ -675,7 +741,9 @@ class ShardWorker:
         return (busy + self.scheduler.queue_depth) / max(self.num_slots, 1)
 
     def has_work(self) -> bool:
-        return self.scheduler.has_work()
+        """Whether a request is queued or in a slot, or a superstep is
+        still in flight."""
+        return self.scheduler.has_work() or self._lane.pending is not None
 
     # -- health / drain ------------------------------------------------------
 
@@ -824,7 +892,7 @@ class ShardWorker:
 
     def _observe_round_time(self, dt: float) -> None:
         # cold (compiling) dispatches never reach here — see
-        # _dispatch_superstep — so the EWMA only sees real round walls
+        # _harvest — so the EWMA only sees real round walls
         self._spr_ewma = dt if self._spr_ewma == 0.0 else (
             0.7 * self._spr_ewma + 0.3 * dt)
 
@@ -937,8 +1005,8 @@ class ShardWorker:
         ``snapshot_rounds`` is the engine round count the packet reflects:
         slots admitted at or after it hold a chain NOT yet present in the
         packet (whose lane still shows the previous, finished occupant) and
-        must not be retired against it — the double-buffered loops harvest
-        packets one superstep behind the dispatch frontier.
+        must not be retired against it — a boundary that overlaps harvests
+        the packet one superstep behind the dispatch frontier.
         """
         sync, snapshot_rounds, R, t_dispatch, cold = pending
         info_dev, samples_dev = sync
@@ -947,8 +1015,8 @@ class ShardWorker:
             tr = None
         with self._span("device_wait", R=R, cold=cold):
             t0 = time.perf_counter()
-            jax.block_until_ready(info_dev)  # waits on the device, off-path
-            t1 = time.perf_counter()  # in the double-buffered serve loops
+            jax.block_until_ready(info_dev)  # off the critical path when
+            t1 = time.perf_counter()  # the next superstep is in flight
         self.stats.device_s += t1 - t0
         with self._span("harvest") as sp:
             finished = self._retire(info_dev, samples_dev, snapshot_rounds,
@@ -956,12 +1024,16 @@ class ShardWorker:
             self.stats.host_sync_s += time.perf_counter() - t1
             self._refresh_health()
             sp.set(retired=len(finished), live_demand=self._live_demand)
+        # a superstep launched while its predecessor ran starts on the
+        # device when that one completes: time it from the later of the two
+        start = max(t_dispatch, self._done_at)
+        self._done_at = done_at if done_at is not None else t1
         if not cold:  # a cold dispatch's elapsed time is mostly jit compile
             # ``done_at``: a fused front end passes ONE completion stamp for
             # the whole boundary, so later shards' EWMAs aren't inflated by
             # their siblings' harvest bookkeeping running first
             end = done_at if done_at is not None else time.perf_counter()
-            self._observe_round_time((end - t_dispatch) / R)
+            self._observe_round_time((end - start) / R)
 
     def _retire(self, info_dev, samples_dev, snapshot_rounds: int, R: int,
                 cold: bool, tr) -> list:

@@ -208,3 +208,199 @@ def test_metrics_unit():
     assert stats.per_request[0].latency == pytest.approx(1.5)
     stats.wall_time = 4.0
     assert stats.throughput() == pytest.approx(0.5)
+
+
+# ---------------------------------------------------------------------------
+# the double-buffered boundary: step() overlaps when nobody waits for a slot
+# ---------------------------------------------------------------------------
+
+
+def test_step_overlaps_with_free_slots(warm_engine, refs, sl_model2,
+                                       sched_tiny):
+    """An open-loop drive with free slots: each call leaves its superstep
+    in flight and harvests the one before it while it runs; samples are
+    still the per-chain asd_sample's, and the counter, the step span and
+    /metrics agree on the overlap."""
+    from repro.serving.obs import TraceRecorder
+    from repro.serving.obs.registry import MetricsRegistry, instrument_engine
+
+    eng = _engine(warm_engine, sl_model2, sched_tiny)
+    eng._tracer = tr = TraceRecorder()
+    reg = instrument_engine(MetricsRegistry(), eng)
+    for r in _requests(2):
+        eng.submit(r)
+    assert eng.step()
+    assert eng._lane.pending is not None  # superstep 1 still in flight
+    assert eng.stats.supersteps == 1 and eng.stats.overlapped_supersteps == 0
+    assert eng.step()
+    assert eng._lane.pending is not None
+    assert eng.stats.overlapped_supersteps == 1  # 2 ran while 1 was read
+    out = {}
+    while eng.step():
+        out.update(eng.drain_results())
+    out.update(eng.drain_results())
+    assert eng._lane.pending is None and not eng.has_work()
+    assert sorted(out) == [0, 1]
+    for i in out:
+        np.testing.assert_array_equal(out[i], np.asarray(refs.sample[i]))
+    # every superstep but the first was launched over its predecessor
+    s = eng.stats
+    assert s.overlapped_supersteps == s.supersteps - 1
+    steps = [sp for sp in tr.spans() if sp["name"] == "step"]
+    assert [sp["args"]["overlap"] for sp in steps] == (
+        [0] + [1] * (s.supersteps - 1) + [0])
+    total = reg.snapshot()["asd_overlapped_supersteps_total"]["samples"]
+    assert total[0]["value"] == s.overlapped_supersteps
+
+
+def test_step_harvests_first_when_queue_outgrows_free_slots(
+        warm_engine, sl_model2, sched_tiny):
+    """With a superstep in flight and more requests queued than free slots,
+    the boundary harvests that superstep before it admits, so the slots it
+    freed are refilled at once."""
+    from repro.serving.obs import TraceRecorder
+
+    eng = _engine(warm_engine, sl_model2, sched_tiny)
+    eng._tracer = tr = TraceRecorder()
+    for r in _requests(2):
+        eng.submit(r)
+    eng.step()
+    assert eng._lane.pending is not None
+    for r in _requests(3, seed0=200):
+        eng.submit(Request(10 + r.rid, key=r.key, y0=r.y0))
+    assert eng.scheduler.queue_depth > len(eng.scheduler.free_slots())
+    eng.step()
+    step = [sp for sp in tr.spans() if sp["name"] == "step"][-1]
+    inside = [sp for sp in tr.spans() if step["t0"] <= sp["t0"]
+              and sp["t0"] + sp["dur"] <= step["t0"] + step["dur"]]
+    names = [sp["name"] for sp in sorted(inside, key=lambda sp: sp["t0"])]
+    assert names.index("harvest") < names.index("admit")
+    assert step["args"]["overlap"] == 0
+    while eng.step():
+        pass
+    assert eng.stats.retired == 5
+
+
+def test_step_serial_while_requests_queue(warm_engine, sl_model2, sched_tiny):
+    """A closed loop with more callers than slots: every call returns with
+    nothing in flight, nothing overlaps, and a slot freed by superstep s
+    takes its next chain at boundary s+1."""
+    eng = _engine(warm_engine, sl_model2, sched_tiny)
+    clients, nxt = 8, 8
+    for r in _requests(clients):
+        eng.submit(r)
+    freed_at = {}  # slot -> engine rounds when its chain retired
+    refills = 0
+    for _ in range(12):
+        before = {s: eng.scheduler.slot_info(s).request.rid
+                  for s in eng.scheduler.active_slots()}
+        for slot, rounds in list(freed_at.items()):
+            assert eng.scheduler.slot_info(slot) is None
+        eng.step()
+        assert eng._lane.pending is None
+        for slot, rounds in list(freed_at.items()):
+            # refilled by this boundary's admission, before its launch
+            info = eng.scheduler.slot_info(slot)
+            assert info is not None and info.admit_round == rounds
+            refills += 1
+            del freed_at[slot]
+        done = eng.drain_results()
+        for slot, rid in before.items():
+            if rid in done:
+                freed_at[slot] = eng.stats.rounds_total
+        for rid in range(nxt, nxt + len(done)):  # each caller sends again
+            eng.submit(_requests(1, seed0=100 + rid)[0])
+        nxt += len(done)
+    assert refills > 0
+    assert eng.stats.overlapped_supersteps == 0
+
+
+def test_retired_chain_drained_by_the_next_launch(warm_engine, sl_model2,
+                                                  sched_tiny):
+    """A chain whose last step commits in superstep s is in drain_results()
+    when the call that launched s+1 returns, and not before."""
+    eng = _engine(warm_engine, sl_model2, sched_tiny)
+    eng.submit(_requests(1)[0])
+    finished_in = None
+    while eng.step():
+        launched = eng.stats.supersteps
+        done = eng.drain_results()
+        if finished_in is not None:
+            assert launched == finished_in + 1 and 0 in done
+            break
+        assert not done
+        if int(eng.chain_state(0).a) >= sched_tiny.K:
+            finished_in = launched
+    assert finished_in is not None
+    while eng.step():
+        pass
+    assert not eng.has_work()
+
+
+def test_round_time_starts_at_predecessor_completion(warm_engine, sl_model2,
+                                                     sched_tiny):
+    """A superstep launched behind its predecessor is timed from the later
+    of its launch and the predecessor's completion, so the seconds-per-round
+    estimate leaves out the wait behind the predecessor."""
+    import time
+
+    eng = _engine(warm_engine, sl_model2, sched_tiny)
+    eng.submit(_requests(1)[0])
+    eng.step()
+    sync, snap, R, t_dispatch, cold = eng._lane.pending
+    assert not cold
+    # as if it had been launched 10 s before its predecessor completed
+    eng._lane.pending = (sync, snap, R, t_dispatch - 10.0, cold)
+    eng._done_at = time.perf_counter()
+    eng._lane.flush()
+    assert 0.0 < eng._spr_ewma < 1.0
+
+
+@pytest.mark.parametrize("traffic", ["free_slots", "queued"])
+@pytest.mark.parametrize("kind", ["continuous", "sharded"])
+def test_chain_state_reads_one_superstep_apart(warm_engine, sl_model2,
+                                               sched_tiny, kind, traffic):
+    """The benchmark's check reads a slot's state after step(), steps once
+    and reads it again: the two reads are exactly one superstep of the
+    engine's own program apart, whether the boundary overlapped or not."""
+    from repro.serving.sharded import ShardedASDEngine
+
+    kw = dict(schedule=sched_tiny, event_shape=(2,), num_slots=4,
+              theta=THETA, eager_head=True, keep_trajectory=True)
+    if kind == "continuous":
+        eng = ContinuousASDEngine(lambda cond: sl_model2, **kw)
+        worker, read = eng, eng.chain_state
+    else:
+        eng = ShardedASDEngine(lambda cond: sl_model2, shards=1, **kw)
+        worker = eng.workers[0]
+        read = lambda slot: eng.chain_state(0, slot)  # noqa: E731
+    eng.adopt_programs(warm_engine)
+    n = 2 if traffic == "free_slots" else 7
+    for r in _requests(n):
+        eng.submit(r)
+    eng.step()
+    fn = worker._get_superstep(1, None)
+    pairs = {True: 0, False: 0}  # by whether the read followed an overlap
+    while eng.has_work():
+        occ = worker.scheduler.active_slots()
+        pre = {s: read(s) for s in occ}
+        batch = jax.tree_util.tree_map(np.array, worker._states)
+        replay, _ = fn(batch, worker._conds, worker._params,
+                       worker._weights_dev)
+        was = worker.stats.overlapped_supersteps
+        eng.step()
+        # in flight exactly when the call launched and nobody is queued
+        assert (worker._lane.pending is not None) == (
+            eng.has_work() and worker.scheduler.queue_depth == 0)
+        overlapped = worker.stats.overlapped_supersteps > was
+        for s, st in pre.items():
+            if int(st.a) >= sched_tiny.K:
+                continue
+            pairs[overlapped] += 1
+            post = read(s)
+            want = jax.tree_util.tree_map(lambda x: x[s], replay)
+            for a, b in zip(jax.tree_util.tree_leaves(post),
+                            jax.tree_util.tree_leaves(want)):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert pairs[True] >= 2
+    assert (pairs[False] >= 2) == (traffic == "queued")

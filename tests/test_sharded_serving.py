@@ -551,7 +551,7 @@ def test_fused_dispatch_parity(warm_single, sl_model2, sched_tiny,
                     reason="needs >= 2 devices (set XLA_FLAGS="
                            "--xla_force_host_platform_device_count)")
 def test_fused_step_drive(sl_model2, sched_tiny):
-    """The synchronous step() drive works in fused mode (open-loop use)."""
+    """The step() drive works in fused mode (open-loop use)."""
     sh = _sharded(sl_model2, sched_tiny, shards=2, dispatch="fused",
                   router=make_router("round-robin"))
     for r in _requests(5, seed0=900):

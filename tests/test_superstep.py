@@ -273,8 +273,8 @@ def test_donation_no_stale_buffers_across_waves(sl_model2, sched_tiny):
 
 
 def test_step_drive_with_supersteps(sl_model2, sched_tiny):
-    """The synchronous step() drive (open-loop path) counts R rounds per
-    step and drains the queue."""
+    """The step() drive (open-loop path) counts R rounds per step and
+    drains the queue."""
     eng = _engine(sl_model2, sched_tiny, rounds_per_sync=2)
     for r in _requests(6):
         eng.submit(r)
